@@ -116,7 +116,7 @@ def _smoke_cases(fast: bool = True) -> list:
         q = jax.random.normal(key, (batch, 1, q_heads, head_dim),
                               jnp.float32)
         kp = jax.random.normal(jax.random.fold_in(key, 1),
-                               (num_pages, page_size, kv_heads, head_dim),
+                               (num_pages, kv_heads, page_size, head_dim),
                                jnp.float32)
         vp = jax.random.normal(jax.random.fold_in(key, 2), kp.shape,
                                jnp.float32)

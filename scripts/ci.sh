@@ -1,8 +1,7 @@
 #!/usr/bin/env bash
 # Tier-1 verification, exactly as ROADMAP.md specifies, with the bounded-
-# runtime guarantee made checkable: the suite must collect cleanly (no
-# hypothesis ImportError — tests/_compat ships an offline shim), pass, and
-# finish within TIMEOUT_S.
+# runtime guarantee made checkable: the suite must collect cleanly, pass,
+# and finish within TIMEOUT_S.
 #
 #   scripts/ci.sh            # full tier-1 (includes -m slow tests)
 #   FAST=1 scripts/ci.sh     # quick signal: skip the slow marker

@@ -18,11 +18,13 @@ under the fused engine; the paper's §2.2 ablations are ``sgd_momentum()``
 from __future__ import annotations
 
 import dataclasses
+import functools
 import inspect
 from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import adalomo as _adalomo
 from repro.core.api import (GroupSpec, Opt, UpdateRule, make_rule,
@@ -48,6 +50,18 @@ def _resolve_backend(backend: str) -> str:
     if backend == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "jnp"
     return backend
+
+
+def _replicated_on_mesh(kernel: Callable) -> Callable:
+    """A ``pallas_call`` has no partitioner.  Under a multi-device mesh in
+    context (``jax.set_mesh``, as the elastic runner sets it) the kernel
+    runs whole on every device inside ``shard_map``: XLA all-gathers its
+    operands, and the caller's shardings slice the replicated result."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return kernel
+    return jax.shard_map(kernel, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
 
 
 def adalomo(cfg: Optional[_adalomo.AdaLomoConfig] = None, *,
@@ -81,10 +95,11 @@ def adalomo(cfg: Optional[_adalomo.AdaLomoConfig] = None, *,
 
     def update_fn(param, grad, state, hp, step):
         if use_pallas and state.v is None and param.ndim >= 2:
-            new_p, nr, nc = adalomo_update(
+            kernel = functools.partial(adalomo_update, cfg=cfg, block=kblock,
+                                       interpret=interpret)
+            new_p, nr, nc = _replicated_on_mesh(kernel)(
                 param, grad, state.r, state.c, hp["lr"], step, hp["beta"],
-                hp["weight_decay"], hp["clip"], cfg=cfg, block=kblock,
-                interpret=interpret)
+                hp["weight_decay"], hp["clip"])
             return new_p, _adalomo.FactoredState(r=nr, c=nc, v=None)
         return _adalomo.update_tensor(
             param, grad, state, lr=hp["lr"], step=step, beta=hp["beta"],

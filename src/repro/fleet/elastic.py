@@ -137,7 +137,8 @@ def run_elastic(spec: RunSpec, *, arch=None, hooks=(), params=None,
 
         def step(params, opt_state, batch, hp, sent):
             batch = jax.device_put(batch, b_sh)
-            return sharded_step(params, opt_state, batch, hp, sent)
+            with jax.set_mesh(mesh):
+                return sharded_step(params, opt_state, batch, hp, sent)
     else:
         sharded_step = jax.jit(program.fn,
                                in_shardings=(p_sh, o_sh, b_sh, hp_sh),
@@ -147,11 +148,18 @@ def run_elastic(spec: RunSpec, *, arch=None, hooks=(), params=None,
         def step(params, opt_state, batch, hp):
             # commit the host batch to its mesh sharding before dispatch
             # (the runner materializes batches on the default device
-            # otherwise)
+            # otherwise); the mesh in context lets kernels without a
+            # partitioner place themselves (core/optimizers.py)
             batch = jax.device_put(batch, b_sh)
-            return sharded_step(params, opt_state, batch, hp)
+            with jax.set_mesh(mesh):
+                return sharded_step(params, opt_state, batch, hp)
+
+    def lower(*args):
+        with jax.set_mesh(mesh):
+            return sharded_step.lower(*args)
 
     step._cache_size = sharded_step._cache_size  # zero-recompile introspection
+    step.lower = lower                           # StepProgram.lower()
     program.step = step
 
     if params is None:

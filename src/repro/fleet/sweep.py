@@ -21,7 +21,10 @@ Fleet properties, all inherited from the run layer rather than re-built:
 
   * **crash isolation** — members run sequentially in-process (failures
     recorded, sweep continues) or as subprocesses (``mode="subprocess"``,
-    bounded by ``parallel``) where a member death cannot touch the driver;
+    bounded by ``parallel``) where a member death cannot touch the driver.
+    An accelerator belongs to one process at a time, so subprocess
+    members run side by side only when ``JAX_PLATFORMS=cpu`` pins them to
+    the host; the driver itself never initializes a JAX backend;
   * **individual resumability** — member specs force ``resume=True`` +
     ``gc_incomplete=True``; re-invoking the sweep skips DONE members and
     resumes killed/preempted ones from their last complete checkpoint
@@ -37,6 +40,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import json
+import os
 import subprocess
 import sys
 import time
@@ -168,16 +172,30 @@ def _run_member_inproc(member: SweepMember, *, log_fn, member_hooks,
     return "done"
 
 
+def member_slots(parallel: int, log_fn=print) -> int:
+    """How many subprocess members may run at once.  Each member that can
+    see an accelerator takes all of it, and a second process would fail
+    or hang on it: only members pinned to the CPU share the host."""
+    parallel = max(1, parallel)
+    if parallel > 1 and os.environ.get("JAX_PLATFORMS") != "cpu":
+        log_fn(f"members may use an accelerator: running 1 at a time "
+               f"(asked for {parallel}; set JAX_PLATFORMS=cpu to run "
+               f"CPU members side by side)")
+        return 1
+    return parallel
+
+
 def _run_members_subprocess(todo: list[SweepMember], *, parallel: int,
                             extra_args: Sequence[str], log_fn) -> dict:
     """Crash-isolated members: each is ``python -m repro.launch.train
-    --spec <member>/spec.json``, at most ``parallel`` in flight."""
+    --spec <member>/spec.json``, at most :func:`member_slots` in flight."""
     from repro.fleet.preempt import PREEMPTED_EXIT_CODE
     statuses: dict[str, str] = {}
     pending = list(todo)
     live: list[tuple[SweepMember, subprocess.Popen, Any]] = []
+    slots = member_slots(parallel, log_fn)
     while pending or live:
-        while pending and len(live) < max(1, parallel):
+        while pending and len(live) < slots:
             m = pending.pop(0)
             log = open(m.dir / "stdout.log", "w")
             cmd = [sys.executable, "-m", "repro.launch.train",

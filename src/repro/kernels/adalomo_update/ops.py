@@ -1,9 +1,9 @@
 """Jitted wrapper for the fused AdaLomo update kernel.
 
 ``adalomo_update(param, grad, r, c, lr, step, beta, weight_decay, clip)``
-— handles padding to block multiples, the tiny host-side r-sum between the
-two kernels, leading stack dims via vmap, and exposes ``interpret=`` for
-CPU validation against ref.py.
+— handles padding to block multiples, the O(m+n) moment EMA and r-sum
+between the two kernels, leading stack dims via vmap, and exposes
+``interpret=`` for CPU validation against ref.py.
 
 All hyperparameters are dynamic operands (Opt v2 contract): lr/β/decay/
 clip may be traced scalars, so schedules and per-group overrides never
@@ -60,12 +60,12 @@ def adalomo_update(param, grad, r, c, lr, step,
     # pad to block multiples (zero rows/cols are inert in every statistic)
     p_p = _pad_to(param, bm, bn)
     g_p = _pad_to(grad, bm, bn)
-    r_p = jnp.pad(r, (0, p_p.shape[0] - m))
-    c_p = jnp.pad(c, (0, p_p.shape[1] - n))
 
-    new_r, new_c = stats_pallas(g_p, r_p, c_p, beta=beta,
-                                eps_stat=cfg.eps_stat, block=(bm, bn),
-                                interpret=interpret)
+    rowsum, colsum_parts = stats_pallas(g_p, eps_stat=cfg.eps_stat,
+                                        block=(bm, bn), interpret=interpret)
+    beta = jnp.asarray(beta, jnp.float32)
+    new_r = beta * r + (1.0 - beta) * rowsum[:m, 0]
+    new_c = beta * c + (1.0 - beta) * jnp.sum(colsum_parts[:, 0, :n], axis=0)
     denom = jnp.maximum(jnp.sum(new_r), cfg.eps_stat)
     if cfg.bias_correction:
         corr = jnp.maximum(
@@ -76,9 +76,12 @@ def adalomo_update(param, grad, r, c, lr, step,
     inv_denom_corr = 1.0 / (denom * corr)
     lr_eff = jnp.asarray(lr, jnp.float32)
     decay = 1.0 - lr_eff * jnp.asarray(weight_decay, jnp.float32)
+    # padded rows/cols of the moments are zero, so û is zero there
+    r_col = jnp.pad(new_r, (0, p_p.shape[0] - m))[:, None]
+    c_row = jnp.pad(new_c, (0, p_p.shape[1] - n))[None, :]
     new_p = update_pallas(
-        p_p, g_p, new_r, new_c, lr=lr_eff, inv_denom_corr=inv_denom_corr,
+        p_p, g_p, r_col, c_row, lr=lr_eff, inv_denom_corr=inv_denom_corr,
         eps_div=cfg.eps_div, clip=clip, eps_rms=cfg.eps_rms,
         n_elems=m * n, decay=decay, literal=cfg.literal_div_v,
         block=(bm, bn), interpret=interpret)
-    return new_p[:m, :n], new_r[:m], new_c[:n]
+    return new_p[:m, :n], new_r, new_c

@@ -67,7 +67,7 @@ def _paged_decode_kernel(bt_ref, sl_ref, scal_ref, q_ref, k_ref, v_ref,
     n_pages = pl.num_programs(2)
     scale, window = scal_ref[0], scal_ref[1]
     n = sl_ref[b]                                        # tokens in cache
-    ps = k_ref.shape[1]
+    ps = k_ref.shape[2]
 
     @pl.when(pi == 0)
     def _():
@@ -79,29 +79,29 @@ def _paged_decode_kernel(bt_ref, sl_ref, scal_ref, q_ref, k_ref, v_ref,
     @pl.when(pi * ps < n)
     def _():
         q = q_ref[0, 0].astype(jnp.float32)              # [G, dh]
-        k = k_ref[0, :, 0].astype(jnp.float32)           # [ps, dh]
-        v = v_ref[0, :, 0].astype(jnp.float32)           # [ps, dh]
-        pos = (pi * ps
-               + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1))[0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        valid = pos < n
-        valid = valid & jnp.where(window > 0,
-                                  (n - 1) - pos < window, True)
-        s = jnp.where(valid[None, :], s, NEG_INF)
+        k = k_ref[0, 0].astype(jnp.float32)              # [ps, dh]
+        v = v_ref[0, 0].astype(jnp.float32)              # [ps, dh]
+        pos = pi * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [G, ps]
+        valid = pos < n                                  # [1, ps]
+        valid = valid & ((window <= 0)
+                         | (((n - 1) - pos).astype(jnp.float32) < window))
+        s = jnp.where(valid, s, NEG_INF)
 
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                              # [G, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[:, 0] * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * corr
                         + jnp.dot(p, v, preferred_element_type=jnp.float32))
-        m_ref[...] = m_new[:, None]
-        l_ref[...] = l_new[:, None]
+        m_ref[...] = m_new
 
     @pl.when(pi == n_pages - 1)
     def _():
-        out = acc_ref[...] / jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         out_ref[0, 0] = out.astype(out_ref.dtype)
 
 
@@ -111,18 +111,21 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_tables,
                                   interpret=False):
     """Paged flash-decoding: each sequence reads its own page list.
 
-    q: [B,H,dh]; k_pages/v_pages: [N, ps, K, dh] (page pool shared across
-    sequences); block_tables: [B,P] int32 page ids in logical order
-    (unallocated tail entries must point at a valid page, e.g. the scratch
-    page 0 — they are masked by seq_lens); seq_lens: [B] int32 token counts
-    *including* the token written this step.  Returns [B,H,dh].
+    q: [B,H,dh]; k_pages/v_pages: [N, K, ps, dh] (page pool shared across
+    sequences; one page holds ps tokens of every KV head, head-major so
+    one head's (ps, dh) tile is a block the TPU compiler accepts: ps a
+    multiple of 8, dh whole); block_tables: [B,P] int32
+    page ids in logical order (unallocated tail entries must point at a
+    valid page, e.g. the scratch page 0 — they are masked by seq_lens);
+    seq_lens: [B] int32 token counts *including* the token written this
+    step.  Returns [B,H,dh].
 
     Grid (B, K, P): the block table is scalar-prefetched so the K/V
     BlockSpec index_map can route each grid step's DMA to the right page —
     the gather never materializes a per-sequence contiguous cache.
     """
     B, H, dh = q.shape
-    N, ps, K, _ = k_pages.shape
+    N, K, ps, _ = k_pages.shape
     P = block_tables.shape[1]
     G = H // K
     scale = scale if scale is not None else dh ** -0.5
@@ -135,10 +138,10 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_tables,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, G, dh), lambda b, k, p, bt, sl: (b, k, 0, 0)),
-            pl.BlockSpec((1, ps, 1, dh),
-                         lambda b, k, p, bt, sl: (bt[b, p], 0, k, 0)),
-            pl.BlockSpec((1, ps, 1, dh),
-                         lambda b, k, p, bt, sl: (bt[b, p], 0, k, 0)),
+            pl.BlockSpec((1, 1, ps, dh),
+                         lambda b, k, p, bt, sl: (bt[b, p], k, 0, 0)),
+            pl.BlockSpec((1, 1, ps, dh),
+                         lambda b, k, p, bt, sl: (bt[b, p], k, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, dh),
                                lambda b, k, p, bt, sl: (b, k, 0, 0)),

@@ -32,7 +32,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
     the CPU/backstop implementation.  ``use_kernel=None`` auto-selects the
     kernel on TPU only.
 
-    q: [B,1,H,dh]; k_pages/v_pages: [N, ps, K, dh]; block_tables: [B,P];
+    q: [B,1,H,dh]; k_pages/v_pages: [N, K, ps, dh]; block_tables: [B,P];
     seq_lens: [B] incl. the current token. Returns [B,1,H,dh]."""
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"

@@ -21,13 +21,17 @@ def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, seq_lens,
     """Dense oracle for the paged kernel: gather each sequence's pages into
     a contiguous cache and run the exact serving-path attention.
 
-    q: [B,H,dh]; k_pages/v_pages: [N, ps, K, dh]; block_tables: [B,P];
+    q: [B,H,dh]; k_pages/v_pages: [N, K, ps, dh]; block_tables: [B,P];
     seq_lens: [B] (counts include the current token). Returns [B,H,dh]."""
     B = q.shape[0]
-    _, ps, K, dh = k_pages.shape
+    _, K, ps, dh = k_pages.shape
     P = block_tables.shape[1]
-    kc = k_pages[block_tables].reshape(B, P * ps, K, dh)
-    vc = v_pages[block_tables].reshape(B, P * ps, K, dh)
+
+    def gather(pages):   # [B,P,K,ps,dh] -> [B, P*ps, K, dh]
+        return pages[block_tables].transpose(0, 1, 3, 2, 4).reshape(
+            B, P * ps, K, dh)
+
+    kc, vc = gather(k_pages), gather(v_pages)
     pos = jnp.arange(P * ps, dtype=jnp.int32)
     kv_pos = jnp.where(pos[None, :] < seq_lens[:, None], pos[None, :], -1)
     q_pos = jnp.maximum(seq_lens - 1, 0).astype(jnp.int32)

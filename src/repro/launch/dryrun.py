@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 No arrays are ever allocated: parameters, optimizer state, batches and KV
@@ -16,6 +13,15 @@ Usage:
 Artifacts: benchmarks/artifacts/dryrun/{arch}__{shape}__{mesh}.json
 (existing artifacts are skipped unless --force).
 """
+import os
+
+if __name__ == "__main__":
+    # 512 host devices for the multi-pod mesh; the count locks at the first
+    # jax use, so it is set before the import (and only for the CLI: the
+    # roofline helpers below are imported by benchmarks)
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                               " --xla_force_host_platform_device_count=512")
+
 import argparse
 import json
 import re
@@ -343,6 +349,9 @@ def main(argv=None):
                          "layout (DataConfig.packing=True); non-train and "
                          "non-packable cells are skipped")
     args = ap.parse_args(argv)
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.configs.shapes import SHAPES
     from repro.models.registry import ARCH_IDS, get_arch

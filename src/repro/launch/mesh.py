@@ -6,11 +6,8 @@ import jax
 
 
 def _mk(shape, axes):
-    if hasattr(jax.sharding, "AxisType"):
-        auto = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=auto)
-    # older jax (< 0.5): no AxisType — make_mesh axes are Auto by default
-    return jax.make_mesh(shape, axes)
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -75,6 +75,8 @@ def main(argv=None):
                          "forwarded to subprocess members)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()   # for in-process members; no backend starts
     variants = _load_variants(args)
     with open(args.base) as f:
         from repro.run.spec import RunSpec

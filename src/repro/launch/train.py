@@ -68,6 +68,9 @@ def main(argv=None):
                     help="write the training history JSON here")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.spec:
         with open(args.spec) as f:
             spec = RunSpec.from_json(f.read())

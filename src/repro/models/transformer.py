@@ -584,7 +584,7 @@ def make_paged_decode_step(cfg: LMConfig, *, use_kernel=None,
                            interpret=False):
     """decode(params, pages, batch) -> (logits [B,vocab], new pages).
 
-    pages: {'k','v': [L, N, ps, K, dh]} — the shared page pool.
+    pages: {'k','v': [L, N, K, ps, dh]} — the shared page pool.
     batch: tokens [B,1]; block_tables [B,P] (page ids, logical order,
     unallocated tail = scratch page 0); seq_lens [B] tokens already cached
     (== position of the incoming token); emit [B] bool — rows that are
@@ -600,7 +600,7 @@ def make_paged_decode_step(cfg: LMConfig, *, use_kernel=None,
         n = batch["seq_lens"].astype(jnp.int32)            # [B]
         emit = batch["emit"]
         B = tokens.shape[0]
-        ps = pages["k"].shape[2]
+        ps = pages["k"].shape[3]
         H, K, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
         x = _embed(outer, cfg, tokens)                     # [B,1,d]
@@ -625,8 +625,8 @@ def make_paged_decode_step(cfg: LMConfig, *, use_kernel=None,
                 k = L.rmsnorm(k, layer_p["attn"]["k_norm"]["scale"])
             q = L.apply_rope(q, sin, cos, cfg.rope_pct)
             k = L.apply_rope(k, sin, cos, cfg.rope_pct)
-            kp = kp.at[pidx, slot].set(k[:, 0])
-            vp = vp.at[pidx, slot].set(v[:, 0])
+            kp = kp.at[pidx, :, slot].set(k[:, 0])       # [B, K, dh]
+            vp = vp.at[pidx, :, slot].set(v[:, 0])
             o = paged_decode_attention(q, kp, vp, bt, n_incl,
                                        window=cfg.window,
                                        use_kernel=use_kernel,
@@ -655,7 +655,7 @@ def make_paged_decode_step(cfg: LMConfig, *, use_kernel=None,
 def init_page_pool(cfg: LMConfig, num_pages: int, page_size: int) -> dict:
     """Zeroed shared KV page pool (page 0 is the engine's scratch page)."""
     assert cfg.mla is None, "paged serving supports GQA caches only"
-    shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads,
+    shape = (cfg.n_layers, num_pages, cfg.n_kv_heads, page_size,
              cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype)}

@@ -463,24 +463,19 @@ class ProfilerHook(Hook):
         self.done = False
 
     def _begin(self, ctx) -> None:
-        try:
-            import jax.profiler
-            jax.profiler.start_trace(self.dir)
-            self.active = True
-        except Exception as e:  # profiler backend unavailable: degrade
-            ctx.log(f"profiler disabled: {type(e).__name__}: {e}")
-            self.done = True
+        # a requested trace that cannot start fails the run: a silently
+        # missing trace would be read as a measurement that was made
+        import jax.profiler
+        jax.profiler.start_trace(self.dir)
+        self.active = True
 
     def _end(self, ctx) -> None:
         if not self.active:
             return
-        try:
-            import jax.profiler
-            jax.profiler.stop_trace()
-        except Exception as e:
-            ctx.log(f"profiler stop failed: {type(e).__name__}: {e}")
         self.active = False
         self.done = True
+        import jax.profiler
+        jax.profiler.stop_trace()
 
     def on_run_start(self, ctx) -> None:
         out = Path(self.dir)

@@ -24,6 +24,7 @@ from typing import Any, Callable, Iterator, Optional, Sequence, Type
 
 import jax
 import jax.numpy as jnp
+from jax.errors import JaxRuntimeError
 
 from repro.run import hooks as hooks_lib
 from repro.run.data import EVAL_SEED_OFFSET, make_batch_iter
@@ -44,14 +45,6 @@ def _host_scalars(tree):
     return jax.tree.map(conv, tree)
 
 
-def _retriable_errors() -> tuple:
-    """Transient device-side failures worth a checkpoint-restore retry
-    (preempted TPU, ICI link flap)."""
-    try:
-        from jax.errors import JaxRuntimeError  # jax >= 0.4.14
-        return (JaxRuntimeError,)
-    except ImportError:  # pragma: no cover
-        return (RuntimeError,)
 
 
 @dataclasses.dataclass
@@ -264,7 +257,6 @@ def run(spec: RunSpec, *, arch=None, program: Optional[StepProgram] = None,
     # checkpoint — or with a caller-injected batch iterator we cannot
     # rewind — the error propagates immediately.  Hooks re-observe the
     # re-executed steps, so the history is the truthful training record.
-    retriable = _retriable_errors()
     failures = 0
     try:
         # on_run_start inside the try: if a hook raises here, earlier
@@ -285,7 +277,7 @@ def run(spec: RunSpec, *, arch=None, program: Optional[StepProgram] = None,
                     (ctx.params, ctx.opt_state, loss, metrics,
                      sent) = program.step(ctx.params, ctx.opt_state, batch,
                                           hp, sent)
-            except retriable as e:
+            except JaxRuntimeError as e:
                 failures += 1
                 if ckpt_manager is not None:
                     ckpt_manager.wait()  # drain any in-flight async save

@@ -340,16 +340,23 @@ class PagedEngine:
                     if victim is req:
                         break
 
-    def _run_chunk(self) -> np.ndarray:
-        """Execute one fixed-shape jitted decode chunk; single host sync."""
+    def lower_decode_chunk(self):
+        """Lower (not run) the fixed-shape decode chunk on the live state —
+        what the device executes each chunk, e.g. to check for the kernel."""
+        return self._decode_chunk.lower(*self._chunk_args())
+
+    def _chunk_args(self) -> tuple:
         tables = build_block_tables(self.scheduler.page_lists(),
                                     self.scfg.max_pages_per_seq)
-        self._pages, tok, n, budget, done, self._key, toks = (
-            self._decode_chunk(
-                self.params, self._pages,
+        return (self.params, self._pages,
                 jnp.asarray(self._tok), jnp.asarray(self._n),
                 jnp.asarray(self._budget), jnp.asarray(self._done),
-                self._key, jnp.asarray(tables)))
+                self._key, jnp.asarray(tables))
+
+    def _run_chunk(self) -> np.ndarray:
+        """Execute one fixed-shape jitted decode chunk; single host sync."""
+        self._pages, tok, n, budget, done, self._key, toks = (
+            self._decode_chunk(*self._chunk_args()))
         # ONE transfer per chunk boundary: all post-chunk state together.
         # repro-lint: disable=R2 — this IS the sanctioned single sync.
         tok, n, budget, done, toks = jax.device_get(
@@ -402,14 +409,18 @@ class PagedEngine:
 
     @staticmethod
     def _scatter_fn(pages, k, v, bt_row, length):
-        """Write prefill K/V ([L,1,S,K,dh]) into the page pool along
-        bt_row; positions >= length land on the scratch page."""
-        ps = pages["k"].shape[2]
+        """Write prefill K/V ([L,1,S,K,dh]) into the page pool
+        ([L,N,K,ps,dh]) along bt_row; positions >= length land on the
+        scratch page."""
+        ps = pages["k"].shape[3]
         P = bt_row.shape[0]
         S = k.shape[2]
         j = jnp.arange(S)
         valid = j < length
         pidx = jnp.where(valid, bt_row[jnp.minimum(j // ps, P - 1)], 0)
         slot = jnp.where(valid, j % ps, 0)
-        return {"k": pages["k"].at[:, pidx, slot].set(k[:, 0]),
-                "v": pages["v"].at[:, pidx, slot].set(v[:, 0])}
+        # the indexed dims lead the update: [S, L, K, dh]
+        return {"k": pages["k"].at[:, pidx, :, slot].set(
+                    k[:, 0].transpose(1, 0, 2, 3)),
+                "v": pages["v"].at[:, pidx, :, slot].set(
+                    v[:, 0].transpose(1, 0, 2, 3))}

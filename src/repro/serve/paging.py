@@ -1,8 +1,9 @@
 """Paged KV cache: fixed-size pages + host-side block-table allocator.
 
 The device holds one shared pool of KV pages per layer
-(``[L, num_pages, page_size, K, dh]``, see
-``models.transformer.init_page_pool``).  Sequences own *logical* runs of
+(``[L, num_pages, K, page_size, dh]``, see
+``models.transformer.init_page_pool``; head-major inside a page so the
+decode kernel reads one head's ``(page_size, dh)`` tile as one block).  Sequences own *logical* runs of
 pages through a block table — an int32 row of page ids in logical order —
 so a sequence's cache never needs to be contiguous and freed pages are
 immediately reusable by newly admitted requests (vLLM's PagedAttention

@@ -82,11 +82,8 @@ def retrying(fn: Callable, *, retries: int = 2,
     ``retriable`` defaults to jax runtime errors; ``on_failure(attempt, e)``
     is the hook where the loop restores from checkpoint."""
     if not retriable:
-        try:
-            from jax.errors import JaxRuntimeError  # jax >= 0.4.14
-            retriable = (JaxRuntimeError,)
-        except ImportError:  # pragma: no cover
-            retriable = (RuntimeError,)
+        from jax.errors import JaxRuntimeError
+        retriable = (JaxRuntimeError,)
 
     def wrapped(*a, **kw):
         for attempt in range(retries + 1):

@@ -3,10 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline CI: deterministic shim (tests/_compat)
-    from hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.adalomo import (DEFAULT_HPARAMS, AdaLomoConfig,
                                 FactoredState, init_state, reconstruct_v,

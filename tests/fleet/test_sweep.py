@@ -8,6 +8,7 @@ import pytest
 from _fleet_common import fleet_spec
 from repro.fleet import (KillAtHook, SimulatedKill, apply_overrides,
                          expand_grid, materialize, member_name, run_sweep)
+from repro.fleet.sweep import member_slots
 from repro.run import RunSpec
 
 VARIANTS = [{"opt.lr": 1e-3}, {"opt.lr": 3e-3},
@@ -47,6 +48,24 @@ def test_member_name_stable_and_safe():
     assert member_name(3, {}) == "03_base"
     weird = member_name(1, {"model/arch": "a b"})
     assert "/" not in weird and " " not in weird
+
+
+@pytest.mark.parametrize("platforms,parallel,want", [
+    ("cpu", 3, 3),       # host-only members share the machine
+    (None, 3, 1),        # members may reach an accelerator: one at a time
+    ("tpu", 2, 1),
+    ("cpu,tpu", 2, 1),
+    (None, 1, 1),
+])
+def test_member_slots_one_process_per_accelerator(monkeypatch, platforms,
+                                                  parallel, want):
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    logs = []
+    assert member_slots(parallel, logs.append) == want
+    assert bool(logs) == (want < parallel)
 
 
 def test_materialize_forces_resumable_members(tmp_path):

@@ -79,3 +79,20 @@ def test_profiler_absent_without_profile_dir(tmp_path):
                     checkpoint=CheckpointSpec(dir=str(tmp_path), every=1)),
               log_fn=lambda s: None)
     assert res.find_hook(ProfilerHook) is None
+
+
+def test_profiler_failure_to_start_raises(tmp_path, monkeypatch):
+    """A requested trace that cannot start fails loudly: a run that
+    silently drops its trace would pass off an untraced window."""
+    import jax.profiler
+
+    def broken(*a, **kw):
+        raise RuntimeError("profiler backend unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", broken)
+    hook = ProfilerHook(tmp_path / "prof", start=0, steps=1)
+    ctx = types.SimpleNamespace(spec=_spec(), start_step=0,
+                                log=lambda s: None)
+    with pytest.raises(RuntimeError, match="profiler backend unavailable"):
+        hook.on_run_start(ctx)
+    assert not hook.active

@@ -35,12 +35,12 @@ def _scatter_setup(key, B, H, K, dh, ps, P, seq_lens):
     N = 1 + B * P                      # page 0 = scratch
     perm = rng.permutation(np.arange(1, N))
     bt = perm.reshape(B, P).astype(np.int32)
-    k_pages = rng.normal(size=(N, ps, K, dh)).astype(np.float32)  # garbage
-    v_pages = rng.normal(size=(N, ps, K, dh)).astype(np.float32)
+    k_pages = rng.normal(size=(N, K, ps, dh)).astype(np.float32)  # garbage
+    v_pages = rng.normal(size=(N, K, ps, dh)).astype(np.float32)
     for b in range(B):
         for p in range(P):
-            k_pages[bt[b, p]] = kc[b, p * ps:(p + 1) * ps]
-            v_pages[bt[b, p]] = vc[b, p * ps:(p + 1) * ps]
+            k_pages[bt[b, p]] = kc[b, p * ps:(p + 1) * ps].transpose(1, 0, 2)
+            v_pages[bt[b, p]] = vc[b, p * ps:(p + 1) * ps].transpose(1, 0, 2)
     return (q, jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(k_pages),
             jnp.asarray(v_pages), jnp.asarray(bt),
             jnp.asarray(seq_lens, dtype=jnp.int32))
