@@ -1,0 +1,51 @@
+"""The benchmark's weights: made in one call from the seed, and made
+again leaf by leaf (or layer by layer) to the same values, as the
+reference and the check need."""
+import jax
+import numpy as np
+
+from _tiny import TINY
+from bench import weights as W
+
+
+def test_leaves_made_again_equal_the_whole_model():
+    cfg = dict(TINY, torch_dtype="bfloat16")
+    seed = 2 ** 36 + 11
+    p = W.init_params(cfg, seed)
+    for path, _ in W.OUTER:
+        node = p["outer"]
+        for k in path.split("."):
+            node = node[k]
+        np.testing.assert_array_equal(np.asarray(node, np.float32),
+                                      np.asarray(W.outer_leaf(cfg, seed,
+                                                              path),
+                                                 np.float32))
+    blocks = p["stacks"]["blocks"]
+    for path, _ in W.BLOCK:
+        node = blocks
+        for k in path.split("."):
+            node = node[k]
+        for layer in range(cfg["num_hidden_layers"]):
+            np.testing.assert_array_equal(
+                np.asarray(node[layer], np.float32),
+                np.asarray(W.layer_leaf(cfg, seed, path, layer),
+                           np.float32))
+
+
+def test_layout_and_types_are_the_programs():
+    from repro.models.registry import get_arch
+    cfg = dict(TINY, torch_dtype="float32")
+    mine = jax.eval_shape(lambda: W.init_params(cfg, 1))
+    prog = jax.eval_shape(lambda: get_arch(
+        "h2o-danube-1.8b", smoke=True).init_params(jax.random.PRNGKey(0)))
+    assert jax.tree.structure(mine) == jax.tree.structure(prog)
+    for a, b in zip(jax.tree.leaves(mine), jax.tree.leaves(prog)):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype)
+
+
+def test_seeds_differ():
+    cfg = dict(TINY, torch_dtype="bfloat16")
+    a = W.outer_leaf(cfg, 1, "head")
+    b = W.outer_leaf(cfg, 2 ** 32 + 1, "head")
+    assert not np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32))
