@@ -20,6 +20,10 @@ hooks, so we express the same dataflow *structurally*:
   consecutive parameters are live".  With (params, opt_state) donated at the
   jit boundary, XLA updates buffers in place.
 
+Each phase carries a named scope (``fwd``; ``bwd`` with ``recompute``,
+``grad`` and ``update`` inside; ``head`` for embedding, loss and the
+outer update), which a profiler trace reports per device op.
+
 Grouped update normalization (paper §3.2) is what makes this a *single*
 backward pass: the trust-ratio normalization in the rule needs only the
 layer-local tensors, never a global gradient norm.  ``global_grad_norm``
@@ -58,10 +62,11 @@ def apply_rule_tree(rule: UpdateRule, params, grads, states, labels, hp,
     s_flat = treedef.flatten_up_to(states)
     l_flat = treedef.flatten_up_to(labels)
     new_p, new_s = [], []
-    for p, g, s, lab in zip(p_flat, g_flat, s_flat, l_flat):
-        np_, ns_ = rule.update(p, g, s, hp[lab], step)
-        new_p.append(np_)
-        new_s.append(ns_)
+    with jax.named_scope("update"):
+        for p, g, s, lab in zip(p_flat, g_flat, s_flat, l_flat):
+            np_, ns_ = rule.update(p, g, s, hp[lab], step)
+            new_p.append(np_)
+            new_s.append(ns_)
     return treedef.unflatten(new_p), treedef.unflatten(new_s)
 
 
@@ -114,7 +119,8 @@ def stack_forward(
         y = body(layer_p, ctx, carry_x, aux)
         return y, saved
 
-    x_out, saved_x = jax.lax.scan(fwd, x, (stacked_params, xs_aux))
+    with jax.named_scope("fwd"):
+        x_out, saved_x = jax.lax.scan(fwd, x, (stacked_params, xs_aux))
     return StackResiduals(saved_x=saved_x, x_out=x_out)
 
 
@@ -157,20 +163,23 @@ def stack_backward_update(
         dx, d_ctx = carry
         layer_p, layer_s, x_in, aux = xs
         # Per-layer remat: re-run the layer forward under vjp.
-        _, vjp = jax.vjp(lambda p, c, xi: body(p, c, xi, aux),
-                         layer_p, ctx, x_in)
-        g_layer, g_ctx, dx_in = vjp(dx)
-        if grad_constraint is not None:
-            g_layer = grad_constraint(g_layer)
+        with jax.named_scope("recompute"):
+            _, vjp = jax.vjp(lambda p, c, xi: body(p, c, xi, aux),
+                             layer_p, ctx, x_in)
+        with jax.named_scope("grad"):
+            g_layer, g_ctx, dx_in = vjp(dx)
+            if grad_constraint is not None:
+                g_layer = grad_constraint(g_layer)
         # >>> the LOMO moment: this layer's grads are consumed *here* <<<
         new_p, new_s = apply_rule_tree(rule, layer_p, g_layer, layer_s,
                                        labels, hp, step)
         return (dx_in, _tree_add(d_ctx, g_ctx)), (new_p, new_s)
 
-    (dx_in, d_ctx), (new_params, new_states) = jax.lax.scan(
-        bwd, (dx_out, d_ctx0),
-        (stacked_params, stacked_states, residuals.saved_x, xs_aux),
-        reverse=True)
+    with jax.named_scope("bwd"):
+        (dx_in, d_ctx), (new_params, new_states) = jax.lax.scan(
+            bwd, (dx_out, d_ctx0),
+            (stacked_params, stacked_states, residuals.saved_x, xs_aux),
+            reverse=True)
     return dx_in, d_ctx, new_params, new_states
 
 
@@ -192,14 +201,17 @@ def stack_grads(
     def bwd(carry, xs):
         dx, d_ctx = carry
         layer_p, x_in, aux = xs
-        _, vjp = jax.vjp(lambda p, c, xi: body(p, c, xi, aux),
-                         layer_p, ctx, x_in)
-        g_layer, g_ctx, dx_in = vjp(dx)
+        with jax.named_scope("recompute"):
+            _, vjp = jax.vjp(lambda p, c, xi: body(p, c, xi, aux),
+                             layer_p, ctx, x_in)
+        with jax.named_scope("grad"):
+            g_layer, g_ctx, dx_in = vjp(dx)
         return (dx_in, _tree_add(d_ctx, g_ctx)), g_layer
 
-    (dx_in, d_ctx), g_stack = jax.lax.scan(
-        bwd, (dx_out, d_ctx0),
-        (stacked_params, residuals.saved_x, xs_aux), reverse=True)
+    with jax.named_scope("bwd"):
+        (dx_in, d_ctx), g_stack = jax.lax.scan(
+            bwd, (dx_out, d_ctx0),
+            (stacked_params, residuals.saved_x, xs_aux), reverse=True)
     return dx_in, d_ctx, g_stack
 
 
@@ -267,8 +279,9 @@ def fused_train_step(
     outer, shared, stacks = params["outer"], params["shared"], params["stacks"]
 
     # ---- forward ----
-    x0, pro_vjp = jax.vjp(lambda o: spec.prologue(o, batch), outer)
-    ctx_act = spec.pro_ctx(outer, batch)
+    with jax.named_scope("head"):
+        x0, pro_vjp = jax.vjp(lambda o: spec.prologue(o, batch), outer)
+        ctx_act = spec.pro_ctx(outer, batch)
     residuals: dict[str, StackResiduals] = {}
     x = x0
     for name, stacked in stacks.items():
@@ -276,11 +289,12 @@ def fused_train_step(
                             residual_constraint=residual_constraint)
         residuals[name] = res
         x = res.x_out
-    loss, epi_vjp, metrics = jax.vjp(
-        lambda o, xx: spec.epilogue(o, xx, batch), outer, x, has_aux=True)
-
-    # ---- backward + inline update ----
-    g_outer_epi, dx = epi_vjp(jnp.ones_like(loss))
+    with jax.named_scope("head"):
+        loss, epi_vjp, metrics = jax.vjp(
+            lambda o, xx: spec.epilogue(o, xx, batch), outer, x,
+            has_aux=True)
+        # ---- backward + inline update ----
+        g_outer_epi, dx = epi_vjp(jnp.ones_like(loss))
 
     def _sqsum(tree):
         leaves = jax.tree.leaves(tree)
@@ -322,13 +336,15 @@ def fused_train_step(
         new_stack_m[name] = new_s
         d_shared = _tree_add(d_shared, d_sh)
 
-    (g_outer_pro,) = pro_vjp(dx)
-    g_outer = _tree_add(g_outer_epi, g_outer_pro)
-    new_outer, new_outer_m = apply_rule_tree(
-        rule, outer, g_outer, moments["outer"], labels["outer"], hp, stepf)
-    new_shared, new_shared_m = apply_rule_tree(
-        rule, shared, d_shared, moments["shared"], labels["shared"], hp,
-        stepf)
+    with jax.named_scope("head"):
+        (g_outer_pro,) = pro_vjp(dx)
+        g_outer = _tree_add(g_outer_epi, g_outer_pro)
+        new_outer, new_outer_m = apply_rule_tree(
+            rule, outer, g_outer, moments["outer"], labels["outer"], hp,
+            stepf)
+        new_shared, new_shared_m = apply_rule_tree(
+            rule, shared, d_shared, moments["shared"], labels["shared"], hp,
+            stepf)
 
     new_params = {"outer": new_outer, "shared": new_shared,
                   "stacks": new_stacks}

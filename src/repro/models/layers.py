@@ -531,23 +531,25 @@ def attention(
     scale = scale if scale is not None else dh ** -0.5
     Skv = k.shape[1]
 
-    if force_direct or max(Sq, Skv) <= _DIRECT_ATTN_MAX_SEQ:
-        mask = _mask_block(q_pos, kv_pos, spec, prefix_len,
-                           q_seg=q_seg, kv_seg=kv_seg)
-        mask = mask[None, None, None] if mask.ndim == 2 else mask[:, None, None]
-        out = _direct_attention(qg, k, v, mask, scale)
-    elif (spec.window is not None and not spec.has_prefix and q_seg is None
-          and Skv > spec.window + _Q_BLOCK):
-        out = _swa_gather_attention(qg, k, v, q_pos, kv_pos, spec, scale,
-                                    _Q_BLOCK)
-    else:
-        from repro.sharding.act import seq_tiles
-        k = shard_act(k, "kv_full")
-        v = shard_act(v, "kv_full")
-        impl = _flash_attention if use_flash_vjp else _block_attention
-        out = impl(qg, k, v, q_pos, kv_pos, spec, prefix_len,
-                   scale, _Q_BLOCK, _KV_BLOCK, tiles=seq_tiles(Sq),
-                   q_seg=q_seg, kv_seg=kv_seg)
+    with jax.named_scope("attention"):
+        if force_direct or max(Sq, Skv) <= _DIRECT_ATTN_MAX_SEQ:
+            mask = _mask_block(q_pos, kv_pos, spec, prefix_len,
+                               q_seg=q_seg, kv_seg=kv_seg)
+            mask = (mask[None, None, None] if mask.ndim == 2
+                    else mask[:, None, None])
+            out = _direct_attention(qg, k, v, mask, scale)
+        elif (spec.window is not None and not spec.has_prefix
+              and q_seg is None and Skv > spec.window + _Q_BLOCK):
+            out = _swa_gather_attention(qg, k, v, q_pos, kv_pos, spec,
+                                        scale, _Q_BLOCK)
+        else:
+            from repro.sharding.act import seq_tiles
+            k = shard_act(k, "kv_full")
+            v = shard_act(v, "kv_full")
+            impl = _flash_attention if use_flash_vjp else _block_attention
+            out = impl(qg, k, v, q_pos, kv_pos, spec, prefix_len,
+                       scale, _Q_BLOCK, _KV_BLOCK, tiles=seq_tiles(Sq),
+                       q_seg=q_seg, kv_seg=kv_seg)
     return out.reshape(B, Sq, H, dv)
 
 
@@ -567,14 +569,16 @@ def decode_attention(
     G = H // K
     scale = scale if scale is not None else dh ** -0.5
     qg = q.reshape(B, 1, K, G, dh)
-    logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, k_cache,
-                        preferred_element_type=jnp.float32) * scale
-    valid = (kv_pos >= 0) & (kv_pos[:, :] <= q_pos[:, None])
-    if window is not None:
-        valid = valid & (q_pos[:, None] - kv_pos < window)
-    logits = jnp.where(valid[:, None, None, None, :], logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(v_cache.dtype), v_cache)
+    with jax.named_scope("attention"):
+        logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, k_cache,
+                            preferred_element_type=jnp.float32) * scale
+        valid = (kv_pos >= 0) & (kv_pos[:, :] <= q_pos[:, None])
+        if window is not None:
+            valid = valid & (q_pos[:, None] - kv_pos < window)
+        logits = jnp.where(valid[:, None, None, None, :], logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(v_cache.dtype),
+                         v_cache)
     return out.reshape(B, 1, H, dh)
 
 
@@ -598,17 +602,20 @@ ACTS = {
 
 def glu_mlp(params: dict, x: Array, act: str = "silu") -> Array:
     """SwiGLU/GeGLU: down( act(gate(x)) * up(x) )."""
-    g = shard_act(dense(x, params["w_gate"]), "ffn")
-    u = shard_act(dense(x, params["w_up"]), "ffn")
-    return shard_act(dense(ACTS[act](g) * u, params["w_down"]), "hidden")
+    with jax.named_scope("mlp"):
+        g = shard_act(dense(x, params["w_gate"]), "ffn")
+        u = shard_act(dense(x, params["w_up"]), "ffn")
+        return shard_act(dense(ACTS[act](g) * u, params["w_down"]),
+                         "hidden")
 
 
 def mlp(params: dict, x: Array, act: str = "gelu") -> Array:
     """Plain 2-layer MLP (whisper)."""
-    h = ACTS[act](shard_act(dense(x, params["w_up"], params.get("b_up")),
-                            "ffn"))
-    return shard_act(dense(h, params["w_down"], params.get("b_down")),
-                     "hidden")
+    with jax.named_scope("mlp"):
+        h = ACTS[act](shard_act(dense(x, params["w_up"], params.get("b_up")),
+                                "ffn"))
+        return shard_act(dense(h, params["w_down"], params.get("b_down")),
+                         "hidden")
 
 
 # --------------------------------------------------------------------------

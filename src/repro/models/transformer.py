@@ -627,10 +627,11 @@ def make_paged_decode_step(cfg: LMConfig, *, use_kernel=None,
             k = L.apply_rope(k, sin, cos, cfg.rope_pct)
             kp = kp.at[pidx, :, slot].set(k[:, 0])       # [B, K, dh]
             vp = vp.at[pidx, :, slot].set(v[:, 0])
-            o = paged_decode_attention(q, kp, vp, bt, n_incl,
-                                       window=cfg.window,
-                                       use_kernel=use_kernel,
-                                       interpret=interpret)
+            with jax.named_scope("attention"):
+                o = paged_decode_attention(q, kp, vp, bt, n_incl,
+                                           window=cfg.window,
+                                           use_kernel=use_kernel,
+                                           interpret=interpret)
             a = L.dense(o.reshape(B, 1, H * dh), layer_p["attn"]["wo"])
             x = x + a
             h = L.norm_apply(layer_p["ln2"], x, kind=cfg.norm)

@@ -48,9 +48,11 @@ class StepEvent:
     """What ``on_step_end`` sees: the 0-based step index, **host** scalars
     (loss, metrics dict, hparams pytree — the runner performs ONE bundled
     ``jax.device_get`` per step and converts scalar leaves to Python
-    floats before dispatch), and the host wall-clock seconds since the
-    previous step.  Hooks must never sync on a device value themselves —
-    that is repro-lint rule R2."""
+    floats before dispatch), and the host wall-clock seconds from the
+    previous step's results reaching the host to this step's (sync to
+    sync, so a slow device step shows in its own ``dt``).  Hooks must
+    never sync on a device value themselves — that is repro-lint rule
+    R2."""
 
     step: int
     loss: Any

@@ -30,6 +30,7 @@ from repro.run import hooks as hooks_lib
 from repro.run.data import EVAL_SEED_OFFSET, make_batch_iter
 from repro.run.program import StepProgram, build_step_program
 from repro.run.spec import RunSpec
+from repro.telemetry.spans import span, step_span
 
 
 def _host_scalars(tree):
@@ -45,6 +46,10 @@ def _host_scalars(tree):
     return jax.tree.map(conv, tree)
 
 
+def _batch_tokens(batch) -> int:
+    """Token positions in a batch (its ``tokens`` array; 0 without one)."""
+    tokens = batch.get("tokens") if isinstance(batch, dict) else None
+    return int(tokens.size) if tokens is not None else 0
 
 
 @dataclasses.dataclass
@@ -267,136 +272,155 @@ def run(spec: RunSpec, *, arch=None, program: Optional[StepProgram] = None,
         t_last = time.time()
         step = start_step
         while step < spec.steps.total:
-            batch = jax.tree.map(jnp.asarray, next(batch_iter))
-            hp = program.hparams_fn(step + 1)
-            try:
-                if sent is None:
-                    ctx.params, ctx.opt_state, loss, metrics = program.step(
-                        ctx.params, ctx.opt_state, batch, hp)
-                else:
-                    (ctx.params, ctx.opt_state, loss, metrics,
-                     sent) = program.step(ctx.params, ctx.opt_state, batch,
-                                          hp, sent)
-            except JaxRuntimeError as e:
-                failures += 1
-                if ckpt_manager is not None:
-                    ckpt_manager.wait()  # drain any in-flight async save
-                # Every stream must rewind for recovery to reproduce the
-                # uninterrupted run: caller-injected train or eval
-                # iterators cannot, so the error propagates instead of
-                # silently diverging the curves.
-                rewindable_eval = all(
-                    h.iter_factory is not None for h in pipeline
-                    if isinstance(h, hooks_lib.EvalHook) and h.every)
-                recoverable = (failures <= spec.fault.retries
-                               and own_batch_iter and rewindable_eval
-                               and ckpt_manager is not None
-                               and ckpt_manager.latest_step() is not None)
-                if not recoverable:
-                    raise
-                # Deterministic (jitterless) exponential backoff before
-                # the restore: attempt n waits base * 2^(n-1), capped.
-                delay = 0.0
-                if spec.fault.retry_backoff_s > 0:
-                    delay = min(
-                        spec.fault.retry_backoff_s * 2.0 ** (failures - 1),
-                        spec.fault.retry_backoff_max_s)
-                    time.sleep(delay)
-                restored, (p, s), _extra = ckpt_manager.restore(
-                    template=(ctx.params, ctx.opt_state))
-                _restore_sentinel(_extra)
-                log_fn(f"step {step} failed ({type(e).__name__}); "
-                       f"restored step {restored} "
-                       f"(attempt {failures}/{spec.fault.retries})")
-                ctx.params, ctx.opt_state = p, s
-                failed_at, step = step, restored
-                batch_iter = _train_iter(restored)
-                for h in pipeline:
-                    h.on_recover(ctx, restored)
-                # after on_recover: the truncation must not eat the event
-                mh = hooks_lib.find_metrics_hook(pipeline)
-                if mh is not None:
-                    mh.annotate("recover", restored, attempt=failures,
-                                failed_step=failed_at, backoff_s=delay)
-                t_last = time.time()
-                continue
-            now = time.time()
-            # The ONE device->host sync of the step loop: hooks receive
-            # plain host scalars (the StepEvent contract) so none of them
-            # ever blocks on a device value again (repro-lint R2).
-            loss_h, metrics_h, hp_h = _host_scalars(
-                jax.device_get((loss, metrics, hp)))
-            ev = hooks_lib.StepEvent(step=step, loss=loss_h,
-                                     metrics=metrics_h,
-                                     hparams=hp_h, dt=now - t_last)
-            t_last = now
-            # The monitor ingests the verdict BEFORE hook dispatch so a
-            # boundary checkpoint persists the current device-state
-            # snapshot; policy *actions* run after the hooks have seen
-            # the step (records first, then recovery).
-            anomalous = False
-            if monitor is not None:
-                verdict = ev.metrics.get("sentinel", {})
-                anomalous = monitor.observe(step, verdict)
-            for h in pipeline:
-                h.on_step_end(ctx, ev)
-            if anomalous:
-                spc = spec.sentinel
-                reason = monitor.classify(verdict)
-                mh = hooks_lib.find_metrics_hook(pipeline)
-                rewindable_eval = all(
-                    h.iter_factory is not None for h in pipeline
-                    if isinstance(h, hooks_lib.EvalHook) and h.every)
-                rollback = (monitor.wants_rollback() and own_batch_iter
-                            and rewindable_eval and ckpt_manager is not None
+            with step_span("repro.train.step", step):
+                with span("repro.train.batch"):
+                    batch = jax.tree.map(jnp.asarray, next(batch_iter))
+                hp = program.hparams_fn(step + 1)
+                try:
+                    with span("repro.train.dispatch", step=step,
+                              tokens=_batch_tokens(batch)):
+                        if sent is None:
+                            (ctx.params, ctx.opt_state, loss,
+                             metrics) = program.step(ctx.params, ctx.opt_state,
+                                                     batch, hp)
+                        else:
+                            (ctx.params, ctx.opt_state, loss, metrics,
+                             sent) = program.step(ctx.params, ctx.opt_state,
+                                                  batch, hp, sent)
+                except JaxRuntimeError as e:
+                    with span("repro.train.recover", step=step):
+                        failures += 1
+                        if ckpt_manager is not None:
+                            # drain any in-flight async save
+                            ckpt_manager.wait()
+                        # Every stream must rewind for recovery to reproduce
+                        # the uninterrupted run: caller-injected train or
+                        # eval iterators cannot, so the error propagates
+                        # instead of silently diverging the curves.
+                        rewindable_eval = all(
+                            h.iter_factory is not None for h in pipeline
+                            if isinstance(h, hooks_lib.EvalHook) and h.every)
+                        recoverable = (
+                            failures <= spec.fault.retries
+                            and own_batch_iter and rewindable_eval
+                            and ckpt_manager is not None
                             and ckpt_manager.latest_step() is not None)
-                action = ("rollback" if rollback else
-                          "backoff" if "backoff" in spc.ladder else "skip")
-                log_fn(f"sentinel: anomaly at step {step} ({reason}) -> "
-                       f"{action} [{monitor.anomalies}/{spc.budget}]")
-                if monitor.exhausted():
-                    # Loudly, and NOT via a retriable error: a run that
-                    # keeps tripping the guard must not silently spin
-                    # through restore cycles.
-                    from repro.sentinel.policy import AnomalyBudgetExceeded
-                    if mh is not None:
-                        mh.record_anomaly(step, reason, action="abort",
-                                          count=monitor.anomalies)
-                    raise AnomalyBudgetExceeded(
-                        f"anomaly budget exhausted: {monitor.anomalies} "
-                        f"anomalies > budget {spc.budget} "
-                        f"(last: {reason} at step {step})")
-                if rollback:
-                    ckpt_manager.wait()
-                    restored, (p, s), _ = ckpt_manager.restore(
-                        template=(ctx.params, ctx.opt_state))
-                    ctx.params, ctx.opt_state = p, s
-                    monitor.quarantine(restored, step + 1)
-                    # The device SentinelState deliberately carries
-                    # forward: the guard's memory (EMA, seen-clock)
-                    # survives the rewind, which also keeps seen-keyed
-                    # injected faults from re-firing on replay.
-                    batch_iter = _train_iter(restored)
-                    for h in pipeline:
-                        h.on_recover(ctx, restored)
-                    if mh is not None:
-                        mh.record_anomaly(restored, reason,
-                                          action="rollback",
-                                          anomaly_step=step,
-                                          quarantine=[restored, step + 1],
-                                          count=monitor.anomalies)
-                    log_fn(f"sentinel: rolled back to step {restored}; "
-                           f"quarantined steps [{restored}, {step + 1})")
-                    step = restored
-                    t_last = time.time()
+                        if not recoverable:
+                            raise
+                        # Deterministic (jitterless) exponential backoff
+                        # before the restore: attempt n waits
+                        # base * 2^(n-1), capped.
+                        delay = 0.0
+                        if spec.fault.retry_backoff_s > 0:
+                            delay = min(spec.fault.retry_backoff_s
+                                        * 2.0 ** (failures - 1),
+                                        spec.fault.retry_backoff_max_s)
+                            time.sleep(delay)
+                        restored, (p, s), _extra = ckpt_manager.restore(
+                            template=(ctx.params, ctx.opt_state))
+                        _restore_sentinel(_extra)
+                        log_fn(f"step {step} failed ({type(e).__name__}); "
+                               f"restored step {restored} "
+                               f"(attempt {failures}/{spec.fault.retries})")
+                        ctx.params, ctx.opt_state = p, s
+                        failed_at, step = step, restored
+                        batch_iter = _train_iter(restored)
+                        for h in pipeline:
+                            h.on_recover(ctx, restored)
+                        # after on_recover: the truncation must not eat the
+                        # event
+                        mh = hooks_lib.find_metrics_hook(pipeline)
+                        if mh is not None:
+                            mh.annotate("recover", restored, attempt=failures,
+                                        failed_step=failed_at, backoff_s=delay)
+                        t_last = time.time()
                     continue
-                if mh is not None:
-                    mh.record_anomaly(
-                        step, reason, action=action,
-                        count=monitor.anomalies,
-                        update_norm=verdict.get("update_norm"),
-                        ema_ref=verdict.get("ema_ref"))
-            step += 1
+                # The ONE device->host sync of the step loop: hooks receive
+                # plain host scalars (the StepEvent contract) so none of them
+                # ever blocks on a device value again (repro-lint R2).
+                with span("repro.train.sync", step=step):
+                    loss_h, metrics_h, hp_h = _host_scalars(
+                        jax.device_get((loss, metrics, hp)))
+                # sync to sync: read once this step's results are on the
+                # host, so dt is this step's (the dispatch returns before
+                # the device is done)
+                now = time.time()
+                ev = hooks_lib.StepEvent(step=step, loss=loss_h,
+                                         metrics=metrics_h,
+                                         hparams=hp_h, dt=now - t_last)
+                t_last = now
+                # The monitor ingests the verdict BEFORE hook dispatch so a
+                # boundary checkpoint persists the current device-state
+                # snapshot; policy *actions* run after the hooks have seen
+                # the step (records first, then recovery).
+                anomalous = False
+                if monitor is not None:
+                    verdict = ev.metrics.get("sentinel", {})
+                    anomalous = monitor.observe(step, verdict)
+                with span("repro.train.hooks", step=step):
+                    for h in pipeline:
+                        h.on_step_end(ctx, ev)
+                if anomalous:
+                    spc = spec.sentinel
+                    reason = monitor.classify(verdict)
+                    mh = hooks_lib.find_metrics_hook(pipeline)
+                    rewindable_eval = all(
+                        h.iter_factory is not None for h in pipeline
+                        if isinstance(h, hooks_lib.EvalHook) and h.every)
+                    rollback = (monitor.wants_rollback() and own_batch_iter
+                                and rewindable_eval
+                                and ckpt_manager is not None
+                                and ckpt_manager.latest_step() is not None)
+                    action = ("rollback" if rollback else
+                              "backoff" if "backoff" in spc.ladder else "skip")
+                    log_fn(f"sentinel: anomaly at step {step} ({reason}) -> "
+                           f"{action} [{monitor.anomalies}/{spc.budget}]")
+                    if monitor.exhausted():
+                        # Loudly, and NOT via a retriable error: a run that
+                        # keeps tripping the guard must not silently spin
+                        # through restore cycles.
+                        from repro.sentinel.policy import AnomalyBudgetExceeded
+                        if mh is not None:
+                            mh.record_anomaly(step, reason, action="abort",
+                                              count=monitor.anomalies)
+                        raise AnomalyBudgetExceeded(
+                            f"anomaly budget exhausted: {monitor.anomalies} "
+                            f"anomalies > budget {spc.budget} "
+                            f"(last: {reason} at step {step})")
+                    if rollback:
+                        with span("repro.train.recover", step=step):
+                            ckpt_manager.wait()
+                            restored, (p, s), _ = ckpt_manager.restore(
+                                template=(ctx.params, ctx.opt_state))
+                            ctx.params, ctx.opt_state = p, s
+                            monitor.quarantine(restored, step + 1)
+                            # The device SentinelState deliberately carries
+                            # forward: the guard's memory (EMA, seen-clock)
+                            # survives the rewind, which also keeps seen-keyed
+                            # injected faults from re-firing on replay.
+                            batch_iter = _train_iter(restored)
+                            for h in pipeline:
+                                h.on_recover(ctx, restored)
+                            if mh is not None:
+                                mh.record_anomaly(restored, reason,
+                                                  action="rollback",
+                                                  anomaly_step=step,
+                                                  quarantine=[restored,
+                                                              step + 1],
+                                                  count=monitor.anomalies)
+                            log_fn(f"sentinel: rolled back to step "
+                                   f"{restored}; quarantined steps "
+                                   f"[{restored}, {step + 1})")
+                            step = restored
+                            t_last = time.time()
+                        continue
+                    if mh is not None:
+                        mh.record_anomaly(
+                            step, reason, action=action,
+                            count=monitor.anomalies,
+                            update_norm=verdict.get("update_norm"),
+                            ema_ref=verdict.get("ema_ref"))
+                step += 1
     finally:
         for h in pipeline:
             h.on_exit(ctx)

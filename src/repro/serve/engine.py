@@ -38,6 +38,7 @@ from repro.serve.paging import (OutOfPages, PageAllocator,
                                 build_block_tables)
 from repro.serve.scheduler import RUNNING, Request, Scheduler
 from repro.telemetry.serve import ServeTelemetry
+from repro.telemetry.spans import span
 
 
 def _sample_tokens(logits, key, temperature):
@@ -203,13 +204,15 @@ class PagedEngine:
             max_new_tokens = self.scfg.max_new_tokens
         if ttl_s is None:
             ttl_s = self.scfg.ttl_s
-        req = Request(rid=next(self._rid), prompt=list(prompt),
-                      max_new_tokens=max_new_tokens,
-                      deadline_s=(self.clock() + ttl_s if ttl_s > 0
-                                  else None))
-        self.requests[req.rid] = req
-        self.scheduler.submit(req)
-        return req.rid
+        rid = next(self._rid)
+        with span("repro.serve.submit", rid=rid, prompt_tokens=len(prompt)):
+            req = Request(rid=rid, prompt=list(prompt),
+                          max_new_tokens=max_new_tokens,
+                          deadline_s=(self.clock() + ttl_s if ttl_s > 0
+                                      else None))
+            self.requests[rid] = req
+            self.scheduler.submit(req)
+        return rid
 
     def generate(self, prompts: list[list[int]],
                  max_new_tokens: Optional[int] = None) -> list[list[int]]:
@@ -258,54 +261,61 @@ class PagedEngine:
     # ---------------------------------------------------------- scheduling
     def step(self) -> None:
         """One scheduling round: expire, admit, decode one chunk, retire."""
-        if self.scheduler.expire(self.clock()):
-            # deactivate the freed slots before the next chunk runs
-            for i, r in enumerate(self.scheduler.slots):
-                if r is None:
-                    self._done[i] = True
-        self._admit_all()
-        if not self.scheduler.running():
-            return
-        self._ensure_ahead_all()
-        t0 = time.perf_counter()
-        toks = self._run_chunk()
-        if self.telemetry is not None:
-            self.telemetry.note_decode(time.perf_counter() - t0)
-            # sample before _collect retires finished sequences, so the
-            # gauge sees the pool pressure the chunk actually ran under
-            self.telemetry.sample(self)
-        self._collect(toks)
+        with span("repro.serve.step"):
+            with span("repro.serve.expire"):
+                if self.scheduler.expire(self.clock()):
+                    # deactivate the freed slots before the next chunk runs
+                    for i, r in enumerate(self.scheduler.slots):
+                        if r is None:
+                            self._done[i] = True
+            self._admit_all()
+            if not self.scheduler.running():
+                return
+            self._ensure_ahead_all()
+            t0 = time.perf_counter()
+            toks = self._run_chunk()
+            if self.telemetry is not None:
+                self.telemetry.note_decode(time.perf_counter() - t0)
+                # sample before _collect retires finished sequences, so the
+                # gauge sees the pool pressure the chunk actually ran under
+                self.telemetry.sample(self)
+            self._collect(toks)
 
     def _admit_all(self) -> None:
-        while True:
-            req = self.scheduler.admit_next()
-            if req is None:
-                return
-            self._start(req)
+        with span("repro.serve.admit"):
+            while True:
+                req = self.scheduler.admit_next()
+                if req is None:
+                    return
+                self._start(req)
 
     def _start(self, req: Request) -> None:
         """(Re-)prefill req's tokens, scatter K/V into its pages, sample
         the first new token, and activate its slot."""
         scfg = self.scfg
-        t0 = time.perf_counter()
-        tokens = req.tokens
-        n = len(tokens)
+        n = len(req.tokens)
         bucket = _bucket_len(n, scfg.bucket_min)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :n] = tokens
-        logits, k, v = self._prefill(
-            self.params, {"tokens": jnp.asarray(toks),
-                          "length": jnp.asarray([n], jnp.int32)})
-        self._prefill_count += 1
-        bt_row = np.zeros((scfg.max_pages_per_seq,), np.int32)
-        bt_row[:len(req.pages)] = req.pages
-        self._pages = self._scatter(self._pages, k, v,
-                                    jnp.asarray(bt_row),
-                                    jnp.asarray(n, jnp.int32))
-        key = jax.random.fold_in(self._key, 2 ** 20 + self._prefill_count)
-        t0_tok = int(jax.device_get(self._sample_jit(logits, key))[0])
-        if self.telemetry is not None:
-            self.telemetry.note_prefill(time.perf_counter() - t0)
+        # the whole prefill, first-token sync included; a request's spans
+        # share its rid
+        with span("repro.serve.prefill", rid=req.rid, tokens=n,
+                  bucket=bucket):
+            t0 = time.perf_counter()
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :n] = req.tokens
+            logits, k, v = self._prefill(
+                self.params, {"tokens": jnp.asarray(toks),
+                              "length": jnp.asarray([n], jnp.int32)})
+            self._prefill_count += 1
+            bt_row = np.zeros((scfg.max_pages_per_seq,), np.int32)
+            bt_row[:len(req.pages)] = req.pages
+            self._pages = self._scatter(self._pages, k, v,
+                                        jnp.asarray(bt_row),
+                                        jnp.asarray(n, jnp.int32))
+            key = jax.random.fold_in(self._key,
+                                     2 ** 20 + self._prefill_count)
+            t0_tok = int(jax.device_get(self._sample_jit(logits, key))[0])
+            if self.telemetry is not None:
+                self.telemetry.note_prefill(time.perf_counter() - t0)
         if req.max_new_tokens > 0:
             req.out.append(t0_tok)
         req.n_cached = n
@@ -322,23 +332,27 @@ class PagedEngine:
     def _ensure_ahead_all(self) -> None:
         """Guarantee every running sequence has pages for the next chunk's
         writes, preempting the youngest sequences on pool exhaustion."""
-        for req in sorted(self.scheduler.running(),
-                          key=lambda r: self.scheduler._admit_idx[r.rid]):
-            if req.status != RUNNING:
-                continue   # preempted by an earlier iteration
-            while True:
-                try:
-                    self.scheduler.ensure_ahead(req, self.scfg.chunk)
-                    break
-                except OutOfPages:
-                    victim = self.scheduler.preempt_latest()
-                    assert victim is not None
-                    # deactivate every slot without a running request
-                    for i, r in enumerate(self.scheduler.slots):
-                        if r is None:
-                            self._done[i] = True
-                    if victim is req:
+        preempted = 0
+        with span("repro.serve.ensure_ahead") as sp:
+            for req in sorted(self.scheduler.running(),
+                              key=lambda r: self.scheduler._admit_idx[r.rid]):
+                if req.status != RUNNING:
+                    continue   # preempted by an earlier iteration
+                while True:
+                    try:
+                        self.scheduler.ensure_ahead(req, self.scfg.chunk)
                         break
+                    except OutOfPages:
+                        victim = self.scheduler.preempt_latest()
+                        assert victim is not None
+                        preempted += 1
+                        # deactivate every slot without a running request
+                        for i, r in enumerate(self.scheduler.slots):
+                            if r is None:
+                                self._done[i] = True
+                        if victim is req:
+                            break
+            sp.set_metadata(preempted=preempted)
 
     def lower_decode_chunk(self):
         """Lower (not run) the fixed-shape decode chunk on the live state —
@@ -355,12 +369,15 @@ class PagedEngine:
 
     def _run_chunk(self) -> np.ndarray:
         """Execute one fixed-shape jitted decode chunk; single host sync."""
-        self._pages, tok, n, budget, done, self._key, toks = (
-            self._decode_chunk(*self._chunk_args()))
-        # ONE transfer per chunk boundary: all post-chunk state together.
-        # repro-lint: disable=R2 — this IS the sanctioned single sync.
-        tok, n, budget, done, toks = jax.device_get(
-            (tok, n, budget, done, toks))
+        with span("repro.serve.chunk",
+                  live=int(np.count_nonzero(~self._done))) as sp:
+            self._pages, tok, n, budget, done, self._key, toks = (
+                self._decode_chunk(*self._chunk_args()))
+            # ONE transfer per chunk boundary: all post-chunk state together.
+            # repro-lint: disable=R2 — the sanctioned single sync.
+            tok, n, budget, done, toks = jax.device_get(
+                (tok, n, budget, done, toks))
+            sp.set_metadata(tokens=int(np.count_nonzero(toks >= 0)))
         # device_get returns read-only views; admissions mutate these
         self._tok, self._n = np.array(tok), np.array(n)
         self._budget, self._done = np.array(budget), np.array(done)
@@ -368,12 +385,16 @@ class PagedEngine:
 
     def _collect(self, toks: np.ndarray) -> None:
         """Append emitted tokens; retire finished sequences (frees pages)."""
-        for req in list(self.scheduler.running()):
-            s = req.slot
-            req.out.extend(int(t) for t in toks[s] if t >= 0)
-            req.n_cached = int(self._n[s])
-            if self._done[s]:
-                self.scheduler.finish(req)
+        finished = 0
+        with span("repro.serve.collect") as sp:
+            for req in list(self.scheduler.running()):
+                s = req.slot
+                req.out.extend(int(t) for t in toks[s] if t >= 0)
+                req.n_cached = int(self._n[s])
+                if self._done[s]:
+                    self.scheduler.finish(req)
+                    finished += 1
+            sp.set_metadata(finished=finished)
 
     # ------------------------------------------------------------- jitted
     def _make_chunk_fn(self):

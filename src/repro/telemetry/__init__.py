@@ -9,9 +9,13 @@ Everything emits into a single schema-versioned JSONL stream format
   ``device_get`` (zero extra recompiles, zero extra host syncs);
 * **serve gauges** (:mod:`~repro.telemetry.serve`) — pool / scheduler /
   time-split sampling at the engine's chunk boundaries;
-* **kernel roofline counters** (:mod:`~repro.telemetry.kernels`) +
-  Chrome-trace export (:mod:`~repro.telemetry.trace`);
+* **kernel roofline counters** (:mod:`~repro.telemetry.kernels`);
 * one merging CLI: ``python -m repro.telemetry.report``.
+
+Timelines are the profiler's own: the program's host spans
+(:func:`~repro.telemetry.spans.span`, names under ``repro.``) and the
+named scopes of its device programs land in the trace that
+``--profile-dir`` writes, on one clock with the device's ops.
 """
 from repro.telemetry.kernels import (KernelCounters, adalomo_update_counters,
                                      counters_for,
@@ -25,7 +29,7 @@ from repro.telemetry.schema import (SCHEMA_VERSION, SchemaError,
                                     validate_bench, validate_bench_dir,
                                     validate_record)
 from repro.telemetry.serve import ServeTelemetry
-from repro.telemetry.trace import chrome_trace, write_chrome_trace
+from repro.telemetry.spans import span, step_span
 from repro.telemetry.writer import TelemetryWriter
 
 __all__ = [
@@ -37,5 +41,5 @@ __all__ = [
     "ServeTelemetry", "TelemetryWriter",
     "KernelCounters", "counters_for", "adalomo_update_counters",
     "paged_decode_attention_counters", "zoo_cases",
-    "chrome_trace", "write_chrome_trace",
+    "span", "step_span",
 ]

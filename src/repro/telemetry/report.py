@@ -15,7 +15,10 @@ no re-derivation, no rounding — so the report is bitwise-faithful to the
 run it summarizes, and its output on a fixed stream is golden-stable.
 
     PYTHONPATH=src python -m repro.telemetry.report out/metrics.jsonl \
-        [serve.jsonl ...] [--json] [--out report.json] [--chrome-trace t.json]
+        [serve.jsonl ...] [--json] [--out report.json]
+
+For a timeline, trace the run with ``--profile-dir``: the profiler's
+trace holds the program's spans and the device's ops on one clock.
 """
 from __future__ import annotations
 
@@ -228,9 +231,6 @@ def main(argv=None) -> int:
                     help="print the summary as JSON instead of text")
     ap.add_argument("--out", default=None,
                     help="also write the JSON summary to this path")
-    ap.add_argument("--chrome-trace", default=None,
-                    help="also export a Chrome-trace/Perfetto JSON of the "
-                         "first stream")
     ap.add_argument("--lenient", action="store_true",
                     help="skip malformed lines instead of failing")
     args = ap.parse_args(argv)
@@ -241,9 +241,6 @@ def main(argv=None) -> int:
     if args.out:
         Path(args.out).write_text(
             json.dumps(summary, indent=1, sort_keys=True) + "\n")
-    if args.chrome_trace:
-        from repro.telemetry.trace import write_chrome_trace
-        write_chrome_trace(streams[0], args.chrome_trace)
     text = (json.dumps(summary, indent=1, sort_keys=True)
             if args.json else render_text(summary))
     sys.stdout.write(text if text.endswith("\n") else text + "\n")

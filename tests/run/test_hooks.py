@@ -3,6 +3,8 @@ pipeline assembly, checkpoint/resume through the one entrypoint, and the
 acceptance guarantee that hooks + schedulable hparams cause **zero
 steady-state recompiles** of the jitted step.
 """
+import time
+
 import numpy as np
 import pytest
 
@@ -10,7 +12,7 @@ from repro.data.pipeline import DataConfig
 from repro.run import (CheckpointSpec, CheckpointHook, EvalSpec, FaultSpec,
                        HeartbeatHook, HistoryHook, Hook, LoggingHook,
                        ModelSpec, OptSpec, RunSpec, StepSpec, StragglerHook,
-                       run)
+                       build_step_program, run)
 
 
 def _spec(total=3, **kw):
@@ -55,6 +57,43 @@ def test_event_sequence_and_payload():
     assert np.isfinite(res.history["loss"]).all()
     # constant schedule recorded through the hook
     assert res.history["lr"] == [pytest.approx(1e-3)] * 3
+
+
+class _SlowResult:
+    """A step result whose host read waits: a long device step as the
+    runner sees it (the dispatch returns at once, the sync waits)."""
+
+    def __init__(self, value, wait_s):
+        self.value, self.wait_s = value, wait_s
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(self.wait_s)
+        return np.asarray(self.value, dtype=dtype)
+
+
+def test_step_dt_is_sync_to_sync_and_lands_on_its_step():
+    slow, wait = 3, 0.5
+    spec = _spec(total=6)
+    prog = build_step_program(spec)
+    real, calls = prog.step, {"n": 0}
+
+    def step(*a):
+        params, opt_state, loss, metrics = real(*a)
+        if calls["n"] == slow:
+            loss = _SlowResult(loss, wait)
+        calls["n"] += 1
+        return params, opt_state, loss, metrics
+
+    prog.step = step
+    dts = {}
+
+    class Dt(Hook):
+        def on_step_end(self, ctx, ev):
+            dts[ev.step] = ev.dt
+
+    run(spec, program=prog, hooks=(Dt(),), log_fn=lambda s: None)
+    assert dts[slow] >= wait
+    assert dts[slow + 1] < wait / 2
 
 
 def test_eval_event_broadcast_to_all_hooks():

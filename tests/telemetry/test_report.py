@@ -1,10 +1,10 @@
 """``repro.telemetry.report``: bitwise reproduction of recorded stream
-values, golden-stable text rendering, and the CLI surface (JSON output,
-Chrome-trace export)."""
+values, golden-stable text rendering, and the CLI surface (JSON
+output)."""
 import json
 from pathlib import Path
 
-from repro.telemetry import chrome_trace, read_stream
+from repro.telemetry import read_stream
 from repro.telemetry.report import main, render_text, summarize
 
 GOLDEN = Path(__file__).parent / "golden"
@@ -68,35 +68,11 @@ def test_merging_split_streams_equals_one_stream(tmp_path):
     assert merged["train"] == whole["train"]
 
 
-def test_cli_json_out_and_chrome_trace(tmp_path, capsys):
+def test_cli_json_out(tmp_path, capsys):
     out = tmp_path / "summary.json"
-    trace = tmp_path / "trace.json"
     rc = main([str(GOLDEN / "train.jsonl"), str(GOLDEN / "serve.jsonl"),
-               "--json", "--out", str(out), "--chrome-trace", str(trace)])
+               "--json", "--out", str(out)])
     assert rc == 0
     printed = json.loads(capsys.readouterr().out)
     assert printed == json.loads(out.read_text())
     assert printed["train"]["final_loss"] == 5.230990409851074
-    tj = json.loads(trace.read_text())
-    assert {e["ph"] for e in tj["traceEvents"]} >= {"X", "i", "M"}
-
-
-def test_chrome_trace_structure():
-    st = read_stream(GOLDEN / "train.jsonl")
-    tj = chrome_trace(st)
-    evs = tj["traceEvents"]
-    steps = [e for e in evs if e["ph"] == "X" and e["name"] == "step"]
-    assert len(steps) == 4
-    # steps tile the cumulative dt clock in microseconds
-    assert steps[1]["ts"] == steps[0]["ts"] + steps[0]["dur"]
-    assert steps[0]["dur"] == 2.0e6
-    instants = [e for e in evs if e["ph"] == "i"]
-    assert {e["name"] for e in instants} == {
-        "probe:opt_health", "probe:factored", "event:straggler"}
-    sv = chrome_trace(read_stream(GOLDEN / "serve.jsonl"))
-    counters = [e for e in sv["traceEvents"] if e["ph"] == "C"]
-    assert any(e["name"] == "pool_util" for e in counters)
-    kr = chrome_trace(read_stream(GOLDEN / "kernel.jsonl"))
-    kx = [e for e in kr["traceEvents"] if e["ph"] == "X"]
-    assert {e["name"] for e in kx} == {"adalomo_update",
-                                       "paged_decode_attention"}
