@@ -161,6 +161,37 @@ def kernels_phase() -> None:
     log(f"[kernels] paged_decode_attention H={H} K={K} dh={dh} ps={ps} "
         f"matches ref.py")
 
+    # flash attention, forward and backward, at danube training widths;
+    # S off the block grid, so the last blocks pad
+    from repro.kernels.flash_attention.ops import flash_attention
+    from repro.kernels.flash_attention.ref import flash_attention_ref
+    B, S, G = 2, 2304, H // K
+    ks = jax.random.split(jax.random.PRNGKey(SEED + 2), 4)
+    q = jax.random.normal(ks[0], (B, S, K, G, dh)).astype(jnp.bfloat16)
+    k = jax.random.normal(ks[1], (B, S, K, dh)).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, S, K, dh)).astype(jnp.bfloat16)
+    do = jax.random.normal(ks[3], (B, S, K, G, dh)).astype(jnp.bfloat16)
+    pos = jnp.arange(S, dtype=jnp.int32)
+
+    def fwd_bwd(attn):
+        def f(q, k, v, do):
+            out, pullback = jax.vjp(attn, q, k, v)
+            return (out,) + pullback(do)
+        return jax.jit(f)
+
+    kw = dict(scale=dh ** -0.5, causal=True, window=cfg.window)
+    got = fwd_bwd(lambda q, k, v: flash_attention(q, k, v, pos, pos, **kw))(
+        q, k, v, do)
+    want = fwd_bwd(lambda q, k, v: flash_attention_ref(
+        q, k, v, pos, pos, **kw)[0])(q, k, v, do)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        rel = float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+        check(rel < 3e-2, f"flash attention {name} differs from ref.py by "
+                          f"{rel:.3e} of its largest element")
+    log(f"[kernels] flash_attention B={B} S={S} H={H} K={K} dh={dh} "
+        f"forward and backward match ref.py")
+
 
 # --------------------------------------------------------------------------
 # Phase: training through run()

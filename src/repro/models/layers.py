@@ -5,7 +5,8 @@ layers compose with the fused-backward engine (``core/fused.py``) and shard
 cleanly under pjit.  Attention supports GQA/MQA, sliding windows (SWA),
 qk-norm, prefix-LM masks and cross-attention, with a two-level blockwise
 (flash-style) path for long sequences that never materializes an S×S score
-matrix.
+matrix.  On a TPU that path runs as the Pallas flash-attention kernels
+(``kernels/flash_attention``) wherever their masks cover it.
 """
 from __future__ import annotations
 
@@ -322,7 +323,8 @@ def _block_attention(q, k, v, q_pos, kv_pos, spec, prefix_len, scale,
 def _flash_attention(q, k, v, q_pos, kv_pos, spec, prefix_len, scale,
                      q_block: int, kv_block: int, tiles: int,
                      q_seg=None, kv_seg=None):
-    """Blockwise attention with a flash-style custom VJP.
+    """Blockwise attention with a flash-style custom VJP (``attention()``
+    takes the Pallas kernels instead on a TPU, where their masks reach).
 
     Differentiating through the online-softmax scan makes jax save every
     per-block softmax intermediate — stacked [nk, B, T, K, G, qb, kb] fp32
@@ -500,6 +502,13 @@ def _swa_gather_attention(q, k, v, q_pos, kv_pos, spec, scale, q_block: int):
     return out[:, :Sq]
 
 
+def _one_device() -> bool:
+    """No multi-device mesh in context: a ``pallas_call`` has no
+    partitioner, so the flash kernels run only where nothing is sharded."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh.empty or mesh.size == 1
+
+
 def attention(
     q: Array,              # [B, Sq, H, dh]
     k: Array,              # [B, Skv, K, dh]
@@ -544,12 +553,21 @@ def attention(
                                         scale, _Q_BLOCK)
         else:
             from repro.sharding.act import seq_tiles
+            tiles = seq_tiles(Sq)
             k = shard_act(k, "kv_full")
             v = shard_act(v, "kv_full")
-            impl = _flash_attention if use_flash_vjp else _block_attention
-            out = impl(qg, k, v, q_pos, kv_pos, spec, prefix_len,
-                       scale, _Q_BLOCK, _KV_BLOCK, tiles=seq_tiles(Sq),
-                       q_seg=q_seg, kv_seg=kv_seg)
+            if (use_flash_vjp and tiles == 1 and Sq == Skv
+                    and not spec.has_prefix and _one_device()
+                    and jax.default_backend() == "tpu"):
+                from repro.kernels.flash_attention.ops import flash_attention
+                out = flash_attention(qg, k, v, q_pos, kv_pos, scale=scale,
+                                      causal=spec.causal, window=spec.window,
+                                      q_seg=q_seg, kv_seg=kv_seg)
+            else:
+                impl = _flash_attention if use_flash_vjp else _block_attention
+                out = impl(qg, k, v, q_pos, kv_pos, spec, prefix_len,
+                           scale, _Q_BLOCK, _KV_BLOCK, tiles=tiles,
+                           q_seg=q_seg, kv_seg=kv_seg)
     return out.reshape(B, Sq, H, dv)
 
 

@@ -20,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.data.pipeline import DataConfig
 from repro.kernels.adalomo_update.ops import adalomo_update
 from repro.kernels.decode_attention.ops import paged_decode_attention
+from repro.kernels.flash_attention.ops import flash_attention
 from repro.models.registry import get_arch
 from repro.run import ModelSpec, OptSpec, RunSpec, StepSpec, build_step_program
 
@@ -83,9 +84,38 @@ def test_paged_decode_attention_compiles_for_v5e(one_chip, arch_id):
     _assert_kernel_compiled(compiled)
 
 
-def test_fused_train_step_compiles_for_v5e(one_chip):
+FLASH_KERNELS = ("flash_attention_fwd", "flash_attention_bwd_dkv",
+                 "flash_attention_bwd_dq")
+
+
+def test_flash_attention_compiles_for_v5e(one_chip):
+    """Forward and backward kernels at h2o-danube-1.8b's widths: B=1,
+    S=4096, 32 query heads over 8 kv heads, dh 80, window 4096."""
+    cfg = get_arch("h2o-danube-1.8b").cfg
+    H, K, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    B, S = 1, 4096
+    pos = jnp.arange(S, dtype=jnp.int32)
+
+    def fwd_bwd(q, k, v, do):
+        out, pullback = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, pos, pos, scale=dh ** -0.5, causal=True,
+            window=cfg.window), q, k, v)
+        return out, pullback(do)
+
+    qs = _sds(one_chip, (B, S, K, H // K, dh), jnp.bfloat16)
+    kv = _sds(one_chip, (B, S, K, dh), jnp.bfloat16)
+    compiled = jax.jit(fwd_bwd).lower(qs, kv, kv, qs).compile()
+    text = compiled.as_text()
+    for name in FLASH_KERNELS:
+        assert name in text, name
+
+
+def test_fused_train_step_compiles_for_v5e(one_chip, monkeypatch):
     """h2o-danube-1.8b at full width, depth cut to 2 layers, with the
-    AdaLomo update on the Pallas kernel inside the reverse scan."""
+    AdaLomo update on the Pallas kernel inside the reverse scan and
+    attention on the flash kernels (the dispatch asks for a TPU backend,
+    which a deviceless compile does not report)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     arch = get_arch("h2o-danube-1.8b")
     arch = dataclasses.replace(arch, cfg=dataclasses.replace(arch.cfg,
                                                              n_layers=2))
@@ -99,6 +129,9 @@ def test_fused_train_step_compiles_for_v5e(one_chip):
     compiled = jax.jit(program.fn, donate_argnums=(0, 1)).lower(
         *args).compile()
     _assert_kernel_compiled(compiled)
+    text = compiled.as_text()
+    for name in FLASH_KERNELS:
+        assert name in text, name
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < V5E_HBM_BYTES)
