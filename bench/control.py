@@ -40,21 +40,22 @@ def half_batch(batch: dict) -> dict:
     return {k: v[:n] for k, v in batch.items()}
 
 
-def train_readings(cfg, traffic, seed, res, control) -> dict:
+def train_readings(cfg, traffic, seed, res, control, devices) -> dict:
     from bench.reference import TrainReference
     from bench.train import readings
     out = {"program": res["readings"]}
     opt = traffic["optimizer"]
     if control:
-        ctl = TrainReference(cfg, opt, "fp8").run(seed, res["batches"])
+        ctl = TrainReference(cfg, opt, "fp8", devices).run(
+            seed, res["batches"])
         out["control"] = readings(ctl, res["ref"])
-        half = TrainReference(cfg, opt).run(
+        half = TrainReference(cfg, opt, devices=devices).run(
             seed, [half_batch(b) for b in res["batches"]])
         out["half_batch"] = readings(half, res["ref"])
     return out
 
 
-def serve_readings(cfg, traffic, seed, res, control) -> dict:
+def serve_readings(cfg, traffic, seed, res, control, devices) -> dict:
     from bench.reference import LogitsReference
     from bench.serve import control_gap, served_gap
     out = {"program": res["readings"]}
@@ -93,6 +94,7 @@ def main(argv=None) -> int:
     traffic = harness.traffic(cell["traffic"])
     limits = harness.load_json(harness.BENCH / "limits"
                                / f"{cell['name']}.json")
+    harness.check_mesh(cell, traffic)
     if traffic["kind"] == "train":
         from bench.train import run_cell
         read = train_readings
@@ -106,7 +108,7 @@ def main(argv=None) -> int:
                 harness.CompileCounter() as counter:
             res = run_cell(cfg, traffic, seed, args.seconds, None, devices,
                            counter, limits)
-            out = read(cfg, traffic, seed, res, seed in control)
+            out = read(cfg, traffic, seed, res, seed in control, devices)
         out.update(seed=seed, seconds=time.perf_counter() - t0,
                    memory=res["memory"], footprint=res["footprint"])
         print(json.dumps(out), flush=True)

@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import math
 
-from bench.weights import BLOCK, OUTER, dims, leaf_shape
+from bench.weights import OUTER, block_leaves, dims, leaf_shape
 
 F32 = 4
 # arithmetic per element of the AdaLomo update: g², its row and column
@@ -36,7 +36,7 @@ def factored_shapes(cfg: dict) -> list[tuple[int, int]]:
     2-D leaves, each layer's slice apart."""
     out = [leaf_shape(cfg, p) for p, _ in OUTER
            if len(leaf_shape(cfg, p)) == 2]
-    per_layer = [leaf_shape(cfg, p) for p, _ in BLOCK
+    per_layer = [leaf_shape(cfg, p) for p, _ in block_leaves(cfg)
                  if len(leaf_shape(cfg, p)) == 2]
     return out + per_layer * dims(cfg)["L"]
 

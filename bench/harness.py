@@ -74,6 +74,17 @@ def cell_metrics(bench: dict, cell: dict, key: str) -> list[dict]:
             if "workloads" not in m or cell["name"] in m["workloads"]]
 
 
+def check_mesh(cell: dict, traffic: dict) -> None:
+    """A traffic mix's ``mesh`` (the program's ``MeshSpec.shape``) must
+    hold the cell's chips, no more and no fewer; without one the cell
+    runs on one chip."""
+    shape = traffic.get("mesh", [])
+    if math.prod(shape) != int(cell["chips"]):
+        raise SystemExit(f"bench: {cell['name']} asks for {cell['chips']} "
+                         f"chips; its traffic {cell['traffic']!r} runs on "
+                         f"a mesh of {shape or [1]}")
+
+
 def require_tpu(n_chips: int):
     """The cell's devices; raises NoChip without a TPU or with too few."""
     import jax

@@ -1,13 +1,18 @@
 """The plain reference for the dense decoder configurations (h2o-danube
-family): the model and AdaLomo written out directly in ``jax.numpy``, in
-float32 with every matmul at ``Precision.HIGHEST``.  It imports nothing
-of the program and takes nothing the program made: it makes its weights
-again from the seed (``bench.weights``) and its batches from the traffic
-generator.
+and Qwen3 families): the model and AdaLomo written out directly in
+``jax.numpy``, in float32 with every matmul at ``Precision.HIGHEST``.  It
+imports nothing of the program and takes nothing the program made: it
+makes its weights again from the seed (``bench.weights``) and its batches
+from the traffic generator.
 
 It runs one layer at a time (weights kept in the served type, upcast per
-layer; attention per (row, key-value head) under ``jax.checkpoint``), so
-a whole-width model fits beside nothing else on one chip.
+layer; attention per (row, key-value head) and the loss per block of
+tokens under ``jax.checkpoint``).  Training keeps each layer's state
+between passes (its weights, moments and input) on one of the cell's
+devices and runs the layer there: the outer leaves and the loss on the
+first, the layers round the others (all on the one device of a one-chip
+cell).  So a model fits beside nothing else on the chips its cell holds,
+and where it is spread the numbers are those of a single-device run.
 
 ``quant`` switches every matmul to a lower precision: the control
 (``fp8``) is this reference computed as float8 training computes it
@@ -20,6 +25,12 @@ halves of each head (theta from the configuration), grouped-query
 attention, causal, inside a sliding window and inside its own document,
 SwiGLU MLP, untied head, mean next-token cross-entropy over the tokens
 that carry a label.
+
+Qwen3 (``"qk_norm": true``), as published for ``Qwen3ForCausalLM``: the
+same layer, with q and k each put through RMSNorm per head, over
+``head_dim`` (weight = 1 + stored value, the configuration's eps), after
+their projections and before the rotary embedding:
+q = RoPE(RMSNorm_q(h W_q)), k = RoPE(RMSNorm_k(h W_k)), v = h W_v.
 
 AdaLomo, as in the paper's Algorithm 1: r, c as EMAs of the row and
 column sums of g² (+1e-30), v = r cᵀ / Σr, bias-corrected; u = g /
@@ -36,6 +47,10 @@ import numpy as np
 from bench import weights as W
 
 HIGHEST = "highest"
+# the loss takes its logits at most this many at a time (512 MiB in
+# float32): a whole row where that fits (danube's 4096 x 32000), halves of
+# rows where the vocabulary is wider (Qwen3's 151936: 512 tokens)
+LOGITS_BLOCK = 2 ** 27
 
 
 def _jnp():
@@ -149,6 +164,9 @@ def block(cfg, quant, w, x, pos, seg):
     q = mm(h, f32["attn.wq"], quant).reshape(B, S, m["H"], m["dh"])
     k = mm(h, f32["attn.wk"], quant).reshape(B, S, m["K"], m["dh"])
     v = mm(h, f32["attn.wv"], quant).reshape(B, S, m["K"], m["dh"])
+    if cfg.get("qk_norm"):
+        q = rmsnorm(q, f32["attn.q_norm.scale"], eps)
+        k = rmsnorm(k, f32["attn.k_norm.scale"], eps)
     q, k = rope(q, pos, theta), rope(k, pos, theta)
     o = attention(q, k, v, pos, seg, cfg.get("sliding_window"), quant)
     x = x + mm(o.reshape(B, S, -1), f32["attn.wo"], quant)
@@ -159,9 +177,16 @@ def block(cfg, quant, w, x, pos, seg):
 
 
 def loss_sums(cfg, quant, final_scale, head, x, labels):
-    """(Σ cross-entropy over labelled tokens, their count), row by row."""
+    """(Σ cross-entropy over labelled tokens, their count), a block of
+    tokens at a time: a row, halved until its logits are at most
+    ``LOGITS_BLOCK``."""
     import jax
     jnp = _jnp()
+    n = x.shape[1]
+    while n * head.shape[1] > LOGITS_BLOCK and n % 2 == 0:
+        n //= 2
+    x = x.reshape(-1, n, x.shape[-1])
+    labels = labels.reshape(-1, n)
 
     def row(args):
         xr, lr = args
@@ -235,9 +260,11 @@ class TrainReference:
     generator and reads, per tensor (each layer's slice apart): the first
     step's gradient norm and the parameters' change after the steps."""
 
-    def __init__(self, cfg: dict, opt: dict, quant: str = "none"):
+    def __init__(self, cfg: dict, opt: dict, quant: str = "none",
+                 devices=None):
         import jax
         self.cfg = W.Static(cfg)
+        self.devices = list(devices or jax.devices()[:1])
         self.hp = {k: float(opt[k]) for k in
                    ("lr", "beta", "clip", "weight_decay", "eps", "eps2")}
         self.q = DOTS[quant]
@@ -268,6 +295,39 @@ class TrainReference:
                             head.astype(jnp.float32), x)
         return (loss,) + vjp(jnp.ones_like(loss))
 
+    def device(self, layer=None):
+        """Where a layer's state lives and its passes run.  The outer
+        leaves (``layer=None``) and the loss live on the first device; on
+        more than one, the layers go round the others, since the loss's
+        vocabulary-wide temporaries leave the first no room for layers."""
+        if layer is None:
+            return self.devices[0]
+        spread = self.devices[1:] or self.devices
+        return spread[layer % len(spread)]
+
+    def made_on(self, dev, make, *args):
+        """``make(cfg, seed, ...)`` of ``bench.weights``, made on ``dev``
+        and committed to it."""
+        import jax
+        with jax.default_device(dev):
+            return jax.device_put(make(self.cfg, *args), dev)
+
+    def update(self, theta, state, grad, t, norms, layer):
+        """AdaLomo step ``t`` of each tensor of ``theta`` that ``grad``
+        holds, in place; at step 1 keeps each gradient's norm in ``norms``
+        under (path, layer).  Returns once the step is done, so that a
+        gradient the caller drops is freed before the next pass: at
+        Qwen3's vocabulary the head's and the embedding's are 3.1 GB each
+        in float32."""
+        import jax
+        jnp = _jnp()
+        for p, g in grad.items():
+            if t == 1:
+                norms[(p, layer)] = _norm(g)
+            theta[p], state[p] = self.upd(theta[p], g.astype(jnp.float32),
+                                          state[p], jnp.float32(t), self.hp)
+        jax.block_until_ready(theta)
+
     def run(self, seed: int, batches: list) -> dict:
         """Reference readings over ``len(batches)`` steps from the seed's
         weights: losses, first gradient norms, change norms."""
@@ -275,58 +335,62 @@ class TrainReference:
         jnp = _jnp()
         cfg = self.cfg
         L, V = W.dims(cfg)["L"], W.dims(cfg)["V"]
-        outer = {p: W.outer_leaf(cfg, seed, p) for p, _ in W.OUTER}
-        layers = [W.layer(cfg, seed, l) for l in range(L)]
+        at = self.device
+        outer = {p: self.made_on(at(), W.outer_leaf, seed, p)
+                 for p, _ in W.OUTER}
+        layers = [self.made_on(at(l), W.layer, seed, l) for l in range(L)]
         o_state = {p: adalomo_init(a.shape) for p, a in outer.items()}
-        l_state = [{p: adalomo_init(a.shape) for p, a in lw.items()}
-                   for lw in layers]
+        l_state = [jax.device_put({p: adalomo_init(a.shape)
+                                   for p, a in lw.items()}, at(l))
+                   for l, lw in enumerate(layers)]
         losses, first_grad = [], {}
         for t, batch in enumerate(batches, start=1):
             tok = jnp.asarray(batch["tokens"])
             lab = jnp.asarray(batch["labels"])
             B, S = tok.shape
-            pos = jnp.asarray(batch.get(
+            pos = np.asarray(batch.get(
                 "positions", np.broadcast_to(np.arange(S), (B, S))))
-            seg = jnp.asarray(batch.get("segment_ids",
-                                        np.ones((B, S), np.int32)))
+            seg = np.asarray(batch.get("segment_ids",
+                                       np.ones((B, S), np.int32)))
+            rows = {d: jax.device_put((pos, seg), d) for d in self.devices}
             x = outer["tok_embed"][tok].astype(jnp.float32)
             xs = []
-            for lw in layers:
+            for l, lw in enumerate(layers):
+                x = jax.device_put(x, at(l))
                 xs.append(x)
-                x = self.fwd(lw, x, pos, seg)
+                x = self.fwd(lw, x, *rows[at(l)])
             loss, g_final, g_head, dx = self.epi(
-                outer["final_norm.scale"], outer["head"], x, lab)
+                outer["final_norm.scale"], outer["head"],
+                jax.device_put(x, at()), lab)
             losses.append(float(loss))
             grads = {}
+            # the head's update needs nothing of the layers' pass: made
+            # first, it frees the head's float32 gradient for it
+            self.update(outer, o_state, {"final_norm.scale": g_final,
+                                         "head": g_head}, t, grads, None)
+            del g_final, g_head
             for l in reversed(range(L)):
-                g_w, dx = self.bwd(layers[l], xs[l], pos, seg, dx)
+                g_w, dx = self.bwd(layers[l], xs[l], *rows[at(l)],
+                                   jax.device_put(dx, at(l)))
                 xs[l] = None
-                if t == 1:
-                    for p, g in g_w.items():
-                        grads[(p, l)] = _norm(g)
-                for p in layers[l]:
-                    layers[l][p], l_state[l][p] = self.upd(
-                        layers[l][p], g_w[p].astype(jnp.float32),
-                        l_state[l][p], jnp.float32(t), self.hp)
-            g_outer = {"final_norm.scale": g_final, "head": g_head,
-                       "tok_embed": self.embed_grad(tok, dx, V)}
+                self.update(layers[l], l_state[l], g_w, t, grads, l)
+                del g_w
+            g_embed = self.embed_grad(tok, jax.device_put(dx, at()), V)
+            self.update(outer, o_state, {"tok_embed": g_embed}, t, grads,
+                        None)
+            del xs, dx, g_embed
             if t == 1:
-                for p, g in g_outer.items():
-                    grads[(p, None)] = _norm(g)
                 first_grad = {k: float(v) for k, v in grads.items()}
-            for p in outer:
-                outer[p], o_state[p] = self.upd(
-                    outer[p], g_outer[p].astype(jnp.float32), o_state[p],
-                    jnp.float32(t), self.hp)
-            del xs
         change = {}
         diff = jax.jit(lambda a, b: _norm(a.astype(jnp.float32)
                                           - b.astype(jnp.float32)))
         for p, a in outer.items():
-            change[(p, None)] = float(diff(a, W.outer_leaf(cfg, seed, p)))
+            change[(p, None)] = float(diff(
+                a, self.made_on(at(), W.outer_leaf, seed, p)))
         for l in range(L):
             for p, a in layers[l].items():
-                change[(p, l)] = float(diff(a, W.layer_leaf(cfg, seed, p, l)))
+                change[(p, l)] = float(diff(
+                    a, self.made_on(at(l), W.layer_leaf, seed, p, l)))
         return {"losses": losses, "grad": first_grad, "change": change}
 
 

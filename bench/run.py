@@ -64,6 +64,7 @@ def execute(bench: dict, cell: dict, seed: int, seconds: float, trace: bool,
     traffic = traffic or harness.traffic(cell["traffic"])
     limits = limits or harness.load_json(
         harness.BENCH / "limits" / f"{cell['name']}.json")
+    harness.check_mesh(cell, traffic)
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     try:
         with harness.CompileCounter() as counter:

@@ -8,9 +8,15 @@ The window closes at the first step end past ``--seconds``; the result
 counts whole steps only, from one ``block_until_ready`` to the next.
 After the window the program's state is freed and the reference runs the
 set-up steps again from the same seed and batches.
+
+A traffic mix with a ``mesh`` (``MeshSpec.shape``, e.g. ``[2, 2]``, data
+× model) runs the cell on that many chips through the program's own mesh
+path: ``run()`` hands the spec to ``fleet/elastic.run_elastic``, and the
+weights are made straight into the parameter shardings that path uses.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import math
 import statistics
@@ -28,27 +34,44 @@ class WindowClosed(Exception):
 
 def program_arch(cfg: dict):
     """The program's architecture for this configuration; its sizes must
-    be the file's."""
+    be the file's.  A file that lists ``num_hidden_layers`` under
+    ``reduced`` (a depth cut: the layers left out stand for further
+    pipeline stages) gives the published depth under ``published``; the
+    registry's architecture, which must have that depth, is then built at
+    the file's."""
     from repro.models.registry import get_arch
     arch = get_arch(cfg["registry_id"], smoke=bool(cfg.get("smoke")))
+    if "num_hidden_layers" in cfg.get("reduced", ()):
+        published = cfg["published"]["num_hidden_layers"]
+        if arch.cfg.n_layers != published:
+            raise SystemExit(
+                f"bench: the program's {cfg['registry_id']} has "
+                f"{arch.cfg.n_layers} layers; the configuration file "
+                f"publishes {published}")
+        arch = dataclasses.replace(arch, cfg=dataclasses.replace(
+            arch.cfg, n_layers=cfg["num_hidden_layers"]))
     c, m = arch.cfg, W.dims(cfg)
     have = {"L": c.n_layers, "d": c.d_model, "H": c.n_heads,
             "K": c.n_kv_heads, "dh": c.head_dim, "f": c.d_ff, "V": c.vocab}
+    qk_norm = bool(cfg.get("qk_norm"))
     if have != m or c.window != cfg.get("sliding_window") or \
-            c.rope_theta != cfg["rope_theta"]:
+            c.rope_theta != cfg["rope_theta"] or c.qk_norm != qk_norm:
         raise SystemExit(f"bench: the program's {cfg['registry_id']} is "
                          f"{have}, window {c.window}, rope_theta "
-                         f"{c.rope_theta}; the configuration file says "
-                         f"{m}, {cfg.get('sliding_window')}, "
-                         f"{cfg['rope_theta']}")
+                         f"{c.rope_theta}, qk_norm {c.qk_norm}; the "
+                         f"configuration file says {m}, "
+                         f"{cfg.get('sliding_window')}, "
+                         f"{cfg['rope_theta']}, {qk_norm}")
     return arch
 
 
 def run_spec(cfg: dict, traffic: dict, seed: int):
     from repro.data.pipeline import DataConfig
     from repro.run import ModelSpec, OptSpec, RunSpec, StepSpec
-    from repro.run.spec import FaultSpec
+    from repro.run.spec import FaultSpec, MeshSpec
     opt = traffic["optimizer"]
+    mesh = (MeshSpec(kind="multi", shape=tuple(traffic["mesh"]))
+            if "mesh" in traffic else MeshSpec())
     return RunSpec(
         model=ModelSpec(cfg["registry_id"], smoke=bool(cfg.get("smoke"))),
         data=DataConfig(vocab=W.dims(cfg)["V"], seq_len=traffic["seq_len"],
@@ -57,8 +80,20 @@ def run_spec(cfg: dict, traffic: dict, seed: int):
                     hparams={k: opt[k] for k in
                              ("beta", "clip", "weight_decay")}),
         steps=StepSpec(total=10 ** 9),
+        mesh=mesh,
         fault=FaultSpec(retries=0),
         seed=seed & 0x7FFFFFFF)
+
+
+def param_shardings(spec, arch):
+    """The shardings the program's mesh path gives the parameters, or
+    None off a mesh."""
+    if spec.mesh.shape is None:
+        return None
+    from repro.fleet.elastic import mesh_from_spec, program_shardings
+    from repro.run import build_step_program
+    return program_shardings(build_step_program(spec, arch),
+                             mesh_from_spec(spec.mesh))[0]
 
 
 # --------------------------------------------------------------------------
@@ -75,7 +110,7 @@ def _tensors(tree: dict, paths) -> dict:
     return out
 
 
-def first_grad_norms(opt_state, beta: float) -> dict:
+def first_grad_norms(cfg: dict, opt_state, beta: float) -> dict:
     """Each tensor's first gradient norm, worked out from the AdaLomo state
     after one step: r = (1-β)·rowsum(g²) (or v = (1-β)·g²), so
     ‖g‖² = Σr / (1-β).  Keys are (path, layer or None)."""
@@ -90,8 +125,9 @@ def first_grad_norms(opt_state, beta: float) -> dict:
     m = opt_state.moments
     outer = jax.device_get({p: norms(s) for p, s in _tensors(
         m["outer"], [p for p, _ in W.OUTER]).items()})
+    paths = [p for p, _ in W.block_leaves(cfg)]
     blocks = jax.device_get({p: norms(s) for p, s in _tensors(
-        m["stacks"]["blocks"], [p for p, _ in W.BLOCK]).items()})
+        m["stacks"]["blocks"], paths).items()})
     out = {(p, None): float(v) for p, v in outer.items()}
     for p, v in blocks.items():
         out.update({(p, l): float(x) for l, x in enumerate(v)})
@@ -113,7 +149,8 @@ def change_norms(cfg: dict, seed: int, params) -> dict:
     out = {}
     for p, a in _tensors(params["outer"], [p for p, _ in W.OUTER]).items():
         out[(p, None)] = gap(a, W.outer_leaf(cfg, seed, p))
-    blocks = _tensors(params["stacks"]["blocks"], [p for p, _ in W.BLOCK])
+    blocks = _tensors(params["stacks"]["blocks"],
+                      [p for p, _ in W.block_leaves(cfg)])
     for p, stack in blocks.items():
         for l in range(stack.shape[0]):
             out[(p, l)] = layer_gap(stack, l, W.layer_leaf(cfg, seed, p, l))
@@ -182,7 +219,8 @@ class BenchHook:
 
     # the Hook protocol of repro.run.hooks
     def on_run_start(self, ctx):
-        pass
+        # the step run() drives: built by run(), or by its mesh path
+        self.c.wrap_step(ctx.program)
 
     def on_recover(self, ctx, step):
         pass
@@ -261,7 +299,8 @@ class TrainCell:
             self.prog["losses"].append(float(ev.loss))
             if s == 0:
                 self.prog["grad"] = first_grad_norms(
-                    ctx.opt_state, self.traffic["optimizer"]["beta"])
+                    self.cfg, ctx.opt_state,
+                    self.traffic["optimizer"]["beta"])
             if s == self.setup_steps - 1:
                 self.prog["change"] = change_norms(self.cfg, self.seed,
                                                    ctx.params)
@@ -303,21 +342,20 @@ class TrainCell:
             jax.profiler.stop_trace()
 
     def run(self):
-        from repro.run import build_step_program, run
+        from repro.run import run
         arch = program_arch(self.cfg)
         spec = run_spec(self.cfg, self.traffic, self.seed)
         with span("bench.setup.weights"):
-            params = W.init_params(self.cfg, self.seed)
-        program = build_step_program(spec, arch)
-        self.wrap_step(program)
+            params = W.init_params(self.cfg, self.seed,
+                                   param_shardings(spec, arch))
         try:
             with harness.stdout_to_stderr():
-                run(spec, program=program, params=params,
+                run(spec, arch=arch, params=params,
                     batch_iter=self.batches(), hooks=(BenchHook(self),),
                     log_fn=log)
         except WindowClosed:
             pass
-        del params, program
+        del params
 
     def window_numbers(self) -> dict:
         steps = range(self.first_window_step, self.last_window_step + 1)
@@ -335,7 +373,8 @@ class TrainCell:
 
     def reference(self) -> dict:
         from bench.reference import TrainReference
-        ref = TrainReference(self.cfg, self.traffic["optimizer"])
+        ref = TrainReference(self.cfg, self.traffic["optimizer"],
+                             devices=self.devices)
         batches = [self.gen.batch(s) for s in range(self.setup_steps)]
         return ref.run(self.seed, batches)
 
@@ -354,6 +393,7 @@ def run_cell(cfg, traffic, seed, seconds, trace_dir, devices, counter,
     t0 = time.perf_counter()
     ref = cell.reference()
     read = readings(cell.prog, ref)
+    read["losses"] = cell.prog["losses"]
     read["reference_s"] = time.perf_counter() - t0
     ok, checks = checks_from(read, limits)
     cell.setup_record["window_open"] = cell.window[0]

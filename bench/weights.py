@@ -8,7 +8,10 @@ its own and equals the slice the whole-model call made.
 
 The tree is the layout the program's dense decoder takes
 (``{"outer", "shared", "stacks": {"blocks"}}``, layers stacked on axis 0,
-matrices ``[in, out]``, RMSNorm weights stored as ``weight - 1``).
+matrices ``[in, out]``, RMSNorm weights stored as ``weight - 1``).  A
+configuration with ``"qk_norm": true`` adds the per-head q and k norms of
+Qwen3 to each block, after the nine leaves every dense decoder has, so
+the keys of those nine stay the same.
 """
 from __future__ import annotations
 
@@ -23,6 +26,7 @@ BLOCK = (("ln1.scale", "norm"), ("ln2.scale", "norm"),
          ("attn.wq", "in"), ("attn.wk", "in"), ("attn.wv", "in"),
          ("attn.wo", "out"), ("mlp.w_gate", "in"), ("mlp.w_up", "in"),
          ("mlp.w_down", "out"))
+QK_NORM = (("attn.q_norm.scale", "norm"), ("attn.k_norm.scale", "norm"))
 
 
 class Static(dict):
@@ -44,6 +48,12 @@ def dims(cfg: dict) -> dict:
             "V": cfg["vocab_size"]}
 
 
+def block_leaves(cfg: dict) -> tuple:
+    """(path, kind) of each block leaf of this configuration, in key
+    order."""
+    return BLOCK + (QK_NORM if cfg.get("qk_norm") else ())
+
+
 def leaf_shape(cfg: dict, path: str) -> tuple:
     m = dims(cfg)
     d, f, V = m["d"], m["f"], m["V"]
@@ -51,6 +61,7 @@ def leaf_shape(cfg: dict, path: str) -> tuple:
     return {"tok_embed": (V, d), "final_norm.scale": (d,), "head": (d, V),
             "ln1.scale": (d,), "ln2.scale": (d,), "attn.wq": (d, qd),
             "attn.wk": (d, kd), "attn.wv": (d, kd), "attn.wo": (qd, d),
+            "attn.q_norm.scale": (m["dh"],), "attn.k_norm.scale": (m["dh"],),
             "mlp.w_gate": (d, f), "mlp.w_up": (d, f),
             "mlp.w_down": (f, d)}[path]
 
@@ -83,7 +94,7 @@ def _outer_leaf(cfg, i, key):
 
 def _layer_leaf(cfg, j, key, layer):
     import jax
-    path, kind = BLOCK[j]
+    path, kind = block_leaves(cfg)[j]
     k = jax.random.fold_in(jax.random.fold_in(key, len(OUTER) + j), layer)
     return _draw(k, leaf_shape(cfg, path), kind, cfg)
 
@@ -105,15 +116,19 @@ def _make(cfg, key):
     outer = {p: _outer_leaf(cfg, i, key) for i, (p, _) in enumerate(OUTER)}
     layers = jnp.arange(dims(cfg)["L"])
     blocks = {p: jax.vmap(partial(_layer_leaf, cfg, j, key))(layers)
-              for j, (p, _) in enumerate(BLOCK)}
+              for j, (p, _) in enumerate(block_leaves(cfg))}
     return {"outer": _nest(outer), "shared": {},
             "stacks": {"blocks": _nest(blocks)}}
 
 
-def init_params(cfg: dict, seed: int):
-    """The whole model, in one jitted call on the default device."""
+def init_params(cfg: dict, seed: int, shardings=None):
+    """The whole model, in one jitted call: on the default device, or
+    straight into ``shardings`` (a tree of the program's parameter
+    shardings), so that no device holds more than its share."""
     import jax
-    return jax.jit(_make, static_argnums=0)(Static(cfg), prng_key(seed))
+    placed = {} if shardings is None else {"out_shardings": shardings}
+    return jax.jit(_make, static_argnums=0, **placed)(Static(cfg),
+                                                      prng_key(seed))
 
 
 def outer_leaf(cfg: dict, seed: int, path: str):
@@ -128,11 +143,11 @@ def layer_leaf(cfg: dict, seed: int, path: str, layer: int):
     """One layer's slice of a block leaf, equal to that slice of
     :func:`init_params`."""
     import jax
-    j = [p for p, _ in BLOCK].index(path)
+    j = [p for p, _ in block_leaves(cfg)].index(path)
     return jax.jit(_layer_leaf, static_argnums=(0, 1))(
         Static(cfg), j, prng_key(seed), layer)
 
 
 def layer(cfg: dict, seed: int, index: int) -> dict:
     """Every block leaf of one layer, as a flat ``{path: array}``."""
-    return {p: layer_leaf(cfg, seed, p, index) for p, _ in BLOCK}
+    return {p: layer_leaf(cfg, seed, p, index) for p, _ in block_leaves(cfg)}

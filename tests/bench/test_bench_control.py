@@ -1,12 +1,12 @@
 """The control: the plain reference computed in float8 (the step below the
 configurations' bfloat16), put in the program's place, comes out not
-correct under each cell's own limits, at a size a test run holds.  On the
-chip the same readings are taken at the cells' sizes by
-``bench/control.py``."""
+correct under each cell's own limits, at a size a test run holds (the
+training control for a danube- and a Qwen3-shaped block).  On the chip
+the same readings are taken at the cells' sizes by ``bench/control.py``."""
 import numpy as np
 import pytest
 
-from _tiny import TINY, train_traffic
+from _tiny import CONFIGS, TINY, train_traffic
 from bench import gen, harness
 from bench.reference import LogitsReference, TrainReference
 from bench.serve import control_gap, served_gap
@@ -21,13 +21,15 @@ def limits(cell: str) -> dict:
     return harness.load_json(harness.BENCH / "limits" / f"{cell}.json")
 
 
-@pytest.fixture(scope="module")
-def train_runs():
+@pytest.fixture(scope="module", params=CONFIGS, ids=CONFIGS)
+def train_runs(request):
+    cfg = dict(CONFIGS[request.param], torch_dtype="bfloat16",
+               vocab_size=1024, sliding_window=None)
     tr = dict(train_traffic(), seq_len=64)
-    g = gen.TrainTraffic(tr, CFG["vocab_size"], SEED)
+    g = gen.TrainTraffic(tr, cfg["vocab_size"], SEED)
     batches = [g.batch(s) for s in range(tr["setup_steps"])]
-    ref = TrainReference(CFG, tr["optimizer"]).run(SEED, batches)
-    ctl = TrainReference(CFG, tr["optimizer"], "fp8").run(SEED, batches)
+    ref = TrainReference(cfg, tr["optimizer"]).run(SEED, batches)
+    ctl = TrainReference(cfg, tr["optimizer"], "fp8").run(SEED, batches)
     return ref, ctl
 
 

@@ -47,15 +47,18 @@ def test_decode_step_flops_by_hand():
         2 * (144 + 40) * 2 + 4 * 2 * 2 * (3 + 5))
 
 
-def test_adalomo_work_by_hand():
+@pytest.mark.parametrize("qk_norm", [False, True])
+def test_adalomo_work_by_hand(qk_norm):
     # 2-D tensors: tok_embed 10x4, head 4x10, and per layer wq 4x4,
-    # wk 4x2, wv 4x2, wo 4x4, gate 4x8, up 4x8, down 8x4
+    # wk 4x2, wv 4x2, wo 4x4, gate 4x8, up 4x8, down 8x4; Qwen3's q and
+    # k norms are 1-D and add none
     shapes = [(10, 4), (4, 10), (4, 4), (4, 2), (4, 2), (4, 4), (4, 8),
               (4, 8), (8, 4)]
-    assert sorted(counters.factored_shapes(SMALL)) == sorted(shapes)
+    small = dict(SMALL, qk_norm=qk_norm)
+    assert sorted(counters.factored_shapes(small)) == sorted(shapes)
     elems = sum(m * n for m, n in shapes)
     sides = sum(m + n for m, n in shapes)
-    w = counters.adalomo_step_work(SMALL)
+    w = counters.adalomo_step_work(small)
     # θ read, g read, θ written once in bf16; r and c read and written
     assert w["bytes"] == 3 * 2 * elems + 2 * 4 * sides
     assert w["flops"] == counters.ADALOMO_OPS_PER_ELEMENT * elems

@@ -91,3 +91,52 @@ def test_reduction_on_hand_made_events():
     assert t.idle_gaps(0, 5) == [("bench.inner", pytest.approx(25e-9)),
                                  ("bench.outer", pytest.approx(10e-9))]
     assert t.top_ops(5)[0] == ("a", pytest.approx(20e-9))
+
+
+def _four_device_step(kernel_ns, other_ns=1000.0):
+    """One ``jit_one_step`` run on each of four devices: an op of the
+    forward's attention, then the AdaLomo kernel under ``update``."""
+    from bench import program_trace
+    ops, mods, scopes = [], [], []
+    for _ in range(4):
+        ops.append([Event("%fusion.1 = f", 10, other_ns),
+                    Event("%adalomo_update.2 = k", 20 + other_ns,
+                          kernel_ns)])
+        scopes.append(["jit(one_step)/fwd/attention/dot_general",
+                       "jit(one_step)/bwd/update/pallas_call"])
+        mods.append([Event("jit_one_step", 0, 40 + other_ns + kernel_ns)])
+    end = 50 + other_ns + kernel_ns
+    t = Trace(ops=ops, modules=mods,
+              spans=[Event(tracefile.WINDOW_SPAN, 0, end)], window=(0, end))
+    program_trace.ProgramTrace(trace=t, spans=[], scopes=scopes)
+    return t
+
+
+@pytest.mark.parametrize("sharded", [True, False])
+def test_training_readers_on_a_four_device_trace(sharded):
+    import numpy as np
+
+    from _tiny import TINY
+    from bench import counters, flops, harness
+    cfg = dict(TINY, torch_dtype="bfloat16")
+    work = counters.adalomo_step_work(cfg)
+    # the kernel's bytes take 1 ms on one chip at this peak
+    peaks = {"bf16_flops_per_s": 1e30, "hbm_bytes_per_s": work["bytes"] / 1e-3}
+    kernel_ns = 1e6 / 4 if sharded else 1e6
+    t = _four_device_step(kernel_ns)
+    batch = {"tokens": np.zeros((4, 32))}
+    f = flops.train_flops(cfg, batch)
+    secs = f / (4 * peaks["bf16_flops_per_s"])      # four chips at peak
+    rec = {"kind": "train", "cfg": cfg, "peaks": peaks,
+           "window": {"steps": 1, "flops": f, "seconds": secs}}
+    read = {m: harness.metric_reader(m)(t, rec) for m in (
+        "adalomo_update_roofline", "mfu.train", "update_share.train",
+        "idle_share.train", "attention_share.train",
+        "fused_update_share.train")}
+    # sharded over the four chips at its roofline: 100%; every chip on
+    # the whole tensors: a quarter
+    assert read["adalomo_update_roofline"] == pytest.approx(
+        100.0 if sharded else 25.0)
+    assert read["mfu.train"] == pytest.approx(100.0)
+    for name, v in read.items():
+        assert v is not None and 0 <= v <= 100 + 1e-9, (name, v)
