@@ -35,6 +35,7 @@ from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 Array = jax.Array
 
@@ -77,6 +78,17 @@ class FactoredState(NamedTuple):
     r: Optional[Array]
     c: Optional[Array]
     v: Optional[Array]
+
+    def pspecs(self, spec: P) -> "FactoredState":
+        """This state's PartitionSpecs for a parameter placed by ``spec``
+        (one entry per parameter dim): r with the rows it sums (the last
+        dim dropped), c with the columns (the second-to-last dropped), v
+        as the parameter."""
+        parts = tuple(spec)
+        return FactoredState(
+            r=None if self.r is None else P(*parts[:-1]),
+            c=None if self.c is None else P(*parts[:-2], parts[-1]),
+            v=None if self.v is None else P(*parts))
 
 
 def _should_factor(shape: tuple[int, ...], cfg: AdaLomoConfig) -> bool:

@@ -35,6 +35,7 @@ grouped-RMS axes see the per-layer tensor shape.
 from __future__ import annotations
 
 import dataclasses
+import inspect
 import math
 import re
 from typing import Any, Callable, Mapping, NamedTuple, Optional, Union
@@ -56,9 +57,12 @@ class UpdateRule(NamedTuple):
     """A per-tensor optimizer rule, v2.
 
     ``init(param, factored=None) -> state`` — per-tensor state (a pytree).
-    ``update(param, grad, state, hp, step) -> (new_param, new_state)`` —
-    one step; ``hp`` is a fully-resolved dict containing every key in
-    ``hparams``; ``step`` is the 1-based global step as float32.
+    ``update(param, grad, state, hp, step, *, sharding=None) ->
+    (new_param, new_state)`` — one step; ``hp`` is a fully-resolved dict
+    containing every key in ``hparams``; ``step`` is the 1-based global
+    step as float32; ``sharding`` is the parameter's ``NamedSharding`` on
+    a mesh (the fused step passes it there), which a rule that runs a
+    kernel without a partitioner needs and any other rule ignores.
     ``hparams`` declares the accepted dynamic hyperparameters and their
     defaults — the introspection surface for schedules and group overrides.
     """
@@ -73,7 +77,15 @@ class UpdateRule(NamedTuple):
 
 def make_rule(name: str, init_fn, update_fn, hparams: Mapping[str, Any]
               ) -> UpdateRule:
-    """Assemble an :class:`UpdateRule`, deriving ``state_bytes`` from init."""
+    """Assemble an :class:`UpdateRule`, deriving ``state_bytes`` from init.
+    An ``update_fn`` without a ``sharding`` keyword (elementwise, or left
+    to XLA's partitioner) is given one it ignores."""
+    if "sharding" not in inspect.signature(update_fn).parameters:
+        plain = update_fn
+
+        def update_fn(param, grad, state, hp, step, *, sharding=None):
+            del sharding
+            return plain(param, grad, state, hp, step)
 
     def state_bytes(param: Array) -> int:
         st = jax.eval_shape(lambda p: init_fn(p), param)

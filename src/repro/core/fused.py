@@ -48,26 +48,37 @@ Array = jax.Array
 # --------------------------------------------------------------------------
 
 def apply_rule_tree(rule: UpdateRule, params, grads, states, labels, hp,
-                    step):
+                    step, shardings=None):
     """Apply ``rule`` leaf-wise with per-group hyperparameters.
 
     ``states`` has one rule-state per param leaf; ``labels`` is an int
     pytree matching ``params`` (group index per leaf, from ``Opt.labels``);
     ``hp`` is the tuple of resolved per-group hparam dicts from
     ``Opt.resolve`` — labels are static, hparam values may be traced.
+    ``shardings`` (on a mesh) holds each leaf's ``NamedSharding``, which
+    the rule is told (the caller has constrained the gradient to it).
     """
     treedef = jax.tree.structure(params)
     p_flat = treedef.flatten_up_to(params)
     g_flat = treedef.flatten_up_to(grads)
     s_flat = treedef.flatten_up_to(states)
     l_flat = treedef.flatten_up_to(labels)
+    sh_flat = (treedef.flatten_up_to(shardings) if shardings is not None
+               else [None] * len(p_flat))
     new_p, new_s = [], []
     with jax.named_scope("update"):
-        for p, g, s, lab in zip(p_flat, g_flat, s_flat, l_flat):
-            np_, ns_ = rule.update(p, g, s, hp[lab], step)
+        for p, g, s, lab, sh in zip(p_flat, g_flat, s_flat, l_flat, sh_flat):
+            np_, ns_ = rule.update(p, g, s, hp[lab], step, sharding=sh)
             new_p.append(np_)
             new_s.append(ns_)
     return treedef.unflatten(new_p), treedef.unflatten(new_s)
+
+
+def _constrain(grads, shardings):
+    """``grads`` constrained leaf-wise to ``shardings`` (None: as they are)."""
+    if shardings is None:
+        return grads
+    return jax.tree.map(jax.lax.with_sharding_constraint, grads, shardings)
 
 
 def _tree_add(a, b):
@@ -137,7 +148,7 @@ def stack_backward_update(
     labels,
     hp,
     step,
-    grad_constraint: Optional[Callable[[Any], Any]] = None,
+    grad_constraint=None,
 ):
     """Reverse scan: per-layer VJP + immediate optimizer update.
 
@@ -145,11 +156,13 @@ def stack_backward_update(
     ``d_ctx`` is the accumulated gradient w.r.t. ``ctx`` (shared weights /
     cross-attended activations), summed over layers in the scan carry.
 
-    ``grad_constraint`` (perf, §Perf H2): constrains each layer gradient to
-    the parameter's sharding *before* the update consumes it.  Under pjit
-    this turns the full-tensor fp32 all-reduce of dW (the ZeRO-2 sin) into
-    a bf16 reduce-scatter; the factored-moment row/col sums then reduce the
-    scattered shard with only O(m+n) cross-shard traffic.
+    ``grad_constraint`` (perf, §Perf H2): one layer's parameter
+    shardings (``NamedSharding`` per leaf, ``sharding/rules.py``
+    ``make_grad_constraint``).  Each layer gradient is constrained to its
+    parameter's sharding *before* the update consumes it, which turns the
+    full-tensor fp32 all-reduce of dW (the ZeRO-2 sin) into a
+    reduce-scatter, and the rule updates each device's shard with only
+    O(m+n) cross-shard traffic for the factored-moment row/col sums.
     """
     L = jax.tree.leaves(stacked_params)[0].shape[0]
     if xs_aux is None:
@@ -168,11 +181,10 @@ def stack_backward_update(
                              layer_p, ctx, x_in)
         with jax.named_scope("grad"):
             g_layer, g_ctx, dx_in = vjp(dx)
-            if grad_constraint is not None:
-                g_layer = grad_constraint(g_layer)
+            g_layer = _constrain(g_layer, grad_constraint)
         # >>> the LOMO moment: this layer's grads are consumed *here* <<<
         new_p, new_s = apply_rule_tree(rule, layer_p, g_layer, layer_s,
-                                       labels, hp, step)
+                                       labels, hp, step, grad_constraint)
         return (dx_in, _tree_add(d_ctx, g_ctx)), (new_p, new_s)
 
     with jax.named_scope("bwd"):
@@ -269,6 +281,10 @@ def fused_train_step(
     computes the global gradient norm (grads discarded layer-by-layer), pass 2
     re-runs backward applying the clipped update — reproducing the paper's
     §2.1 "two backward passes" cost for the Appendix-B comparison.
+
+    ``grad_constraint(group)`` (on a mesh) gives the parameter shardings
+    of one layer of the stack ``group``, or of the ``"outer"`` and
+    ``"shared"`` leaves; the update of each runs on its shards.
     """
     rule = opt.rule
     hp = opt.resolve(hparams)
@@ -339,12 +355,15 @@ def fused_train_step(
     with jax.named_scope("head"):
         (g_outer_pro,) = pro_vjp(dx)
         g_outer = _tree_add(g_outer_epi, g_outer_pro)
+        sh_outer, sh_shared = ((grad_constraint("outer"),
+                                grad_constraint("shared"))
+                               if grad_constraint is not None else (None, None))
         new_outer, new_outer_m = apply_rule_tree(
-            rule, outer, g_outer, moments["outer"], labels["outer"], hp,
-            stepf)
+            rule, outer, _constrain(g_outer, sh_outer), moments["outer"],
+            labels["outer"], hp, stepf, sh_outer)
         new_shared, new_shared_m = apply_rule_tree(
-            rule, shared, d_shared, moments["shared"], labels["shared"], hp,
-            stepf)
+            rule, shared, _constrain(d_shared, sh_shared), moments["shared"],
+            labels["shared"], hp, stepf, sh_shared)
 
     new_params = {"outer": new_outer, "shared": new_shared,
                   "stacks": new_stacks}

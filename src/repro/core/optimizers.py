@@ -52,16 +52,46 @@ def _resolve_backend(backend: str) -> str:
     return backend
 
 
-def _replicated_on_mesh(kernel: Callable) -> Callable:
-    """A ``pallas_call`` has no partitioner.  Under a multi-device mesh in
-    context (``jax.set_mesh``, as the elastic runner sets it) the kernel
-    runs whole on every device inside ``shard_map``: XLA all-gathers its
-    operands, and the caller's shardings slice the replicated result."""
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty or mesh.size == 1:
-        return kernel
-    return jax.shard_map(kernel, mesh=mesh, in_specs=P(), out_specs=P(),
-                         check_vma=False)
+def _on_shards(kernel: Callable, sharding) -> Callable:
+    """The kernel on each device's shard of a tensor placed by ``sharding``
+    (a ``NamedSharding``), inside ``shard_map`` with the parameter's own
+    spec: θ and g in and θ' out as the shards they are, r with the rows,
+    c with the columns, leading stack dims (MoE experts) as they lie.  The
+    kernel adds its row and column sums and the grouped normalization's
+    sums over the axes that split the last two dims, so each shard takes
+    the whole tensor's update.  A ``pallas_call`` has no partitioner, so
+    without a sharding a multi-device mesh in context (``jax.set_mesh``)
+    runs it on a replicated spec: whole, on every device."""
+    if sharding is None:
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty or mesh.size == 1:
+            return kernel
+        spec = P()
+    else:
+        mesh, spec = sharding.mesh, sharding.spec
+
+    def run(param, grad, r, c, *scalars):
+        parts = (tuple(spec) + (None,) * param.ndim)[:param.ndim]
+        rows, cols = parts[-2:]
+        on_shards = jax.shard_map(
+            functools.partial(kernel, row_axes=_axes(rows),
+                              col_axes=_axes(cols)),
+            mesh=mesh,
+            in_specs=(P(*parts), P(*parts), P(*parts[:-1]),
+                      P(*parts[:-2], cols)) + (P(),) * len(scalars),
+            out_specs=(P(*parts), P(*parts[:-1]), P(*parts[:-2], cols)),
+            check_vma=False)
+        return on_shards(param, grad, r, c,
+                         *(jnp.asarray(x, jnp.float32) for x in scalars))
+
+    return run
+
+
+def _axes(entry) -> tuple:
+    """The mesh axes of one dimension of a PartitionSpec."""
+    if entry is None:
+        return ()
+    return tuple(entry) if isinstance(entry, tuple) else (entry,)
 
 
 def adalomo(cfg: Optional[_adalomo.AdaLomoConfig] = None, *,
@@ -80,6 +110,9 @@ def adalomo(cfg: Optional[_adalomo.AdaLomoConfig] = None, *,
     runs the kernel in interpreter mode (CPU validation).
     ``lr``/``beta``/``weight_decay``/``clip`` set the rule's *default*
     dynamic hparams; call-time values override them without recompiling.
+    ``sharding`` (the parameter's ``NamedSharding``, given by the fused
+    step on a mesh) runs the kernel on each device's shard; without it a
+    multi-device mesh in context runs the kernel whole on every device.
     """
     cfg = cfg or _adalomo.AdaLomoConfig()
     use_pallas = _resolve_backend(backend) == "pallas"
@@ -93,11 +126,11 @@ def adalomo(cfg: Optional[_adalomo.AdaLomoConfig] = None, *,
             cfg, factored=factored)
         return _adalomo.init_state(param, c)
 
-    def update_fn(param, grad, state, hp, step):
+    def update_fn(param, grad, state, hp, step, *, sharding=None):
         if use_pallas and state.v is None and param.ndim >= 2:
             kernel = functools.partial(adalomo_update, cfg=cfg, block=kblock,
                                        interpret=interpret)
-            new_p, nr, nc = _replicated_on_mesh(kernel)(
+            new_p, nr, nc = _on_shards(kernel, sharding)(
                 param, grad, state.r, state.c, hp["lr"], step, hp["beta"],
                 hp["weight_decay"], hp["clip"])
             return new_p, _adalomo.FactoredState(r=nr, c=nc, v=None)

@@ -13,7 +13,7 @@ three pieces that make that real:
     NamedShardings for the program's abstract signature from the
     partition rules in ``sharding/rules.py``.  AdaLomo's factored (r, c)
     second-moment vectors land on the devices that own the rows/columns
-    they describe (``opt_pspecs`` shape-suffix matching) — the regime of
+    they describe (``opt_pspecs``) — the regime of
     Anil et al., *Memory-Efficient Adaptive Optimization*;
   * :func:`run_elastic` — re-jit the *same* ``StepProgram.fn`` under
     those shardings and drive it through the stock ``run()`` loop with a
@@ -82,6 +82,23 @@ def program_shardings(program: StepProgram, mesh: Mesh):
     return out
 
 
+def grad_constraint(spec: RunSpec, arch, mesh: Mesh):
+    """The fused step's gradient shardings on ``mesh``
+    (``sharding/rules.make_grad_constraint``): each gradient is
+    reduce-scattered to its parameter's sharding and updated there, shard
+    by shard.  None for the unfused path, which takes no constraints, and
+    for the baseline ``MeshSpec(optimized=False)``: its gradients arrive
+    all-reduced whole and the update runs whole on every device."""
+    if not (spec.mesh.optimized
+            and spec.steps.resolved_fused(spec.opt.name)):
+        return None
+    if arch is None:
+        from repro.models.registry import get_arch
+        arch = get_arch(spec.model.arch, smoke=spec.model.smoke)
+    params = jax.eval_shape(lambda: arch.init_params(jax.random.PRNGKey(0)))
+    return R.make_grad_constraint(mesh, R.MeshAxes(mesh), params)
+
+
 class ElasticCheckpoints:
     """A CheckpointManager view whose ``restore`` defaults to re-sharding
     onto the elastic mesh — the runner's resume and fault-recovery paths
@@ -108,14 +125,18 @@ def run_elastic(spec: RunSpec, *, arch=None, hooks=(), params=None,
     """``run()`` with the step executed on the ``spec.mesh.shape`` mesh.
 
     Called by ``run()`` itself whenever the spec names a mesh shape; the
-    signature mirrors ``run()``'s overrides.  Builds the program once,
-    re-jits its pure ``fn`` under rule-derived shardings (donated, like
-    the single-process step), places initial state on the mesh, and
+    signature mirrors ``run()``'s overrides.  Builds the program once
+    (the fused step with its gradients constrained to the parameters'
+    shardings and updated shard by shard), re-jits its pure ``fn`` under
+    rule-derived shardings (donated, like the single-process step),
+    places initial state on the mesh, and
     hands everything back to the stock loop — resume/recovery restore
     through :class:`ElasticCheckpoints`, landing state on the new mesh.
     """
     mesh = mesh_from_spec(spec.mesh)
-    program = build_step_program(spec, arch, groups=groups, inject=inject)
+    program = build_step_program(spec, arch, groups=groups, inject=inject,
+                                 grad_constraint=grad_constraint(spec, arch,
+                                                                 mesh))
     shardings = program_shardings(program, mesh)
     p_sh, o_sh, b_sh, hp_sh = shardings[:4]
 
@@ -148,8 +169,10 @@ def run_elastic(spec: RunSpec, *, arch=None, hooks=(), params=None,
         def step(params, opt_state, batch, hp):
             # commit the host batch to its mesh sharding before dispatch
             # (the runner materializes batches on the default device
-            # otherwise); the mesh in context lets kernels without a
-            # partitioner place themselves (core/optimizers.py)
+            # otherwise); the mesh in context keeps single-device kernels
+            # off the mesh (models/layers.py) and runs the AdaLomo kernel
+            # whole on every device where no sharding reaches it
+            # (core/optimizers.py)
             batch = jax.device_put(batch, b_sh)
             with jax.set_mesh(mesh):
                 return sharded_step(params, opt_state, batch, hp)

@@ -238,8 +238,11 @@ def make_fused_train_step(cfg: EncDecConfig, opt):
 
         g_outer = F._tree_add(F._tree_add(g_outer_epi, g_outer_dpro),
                               g_outer_enorm)
+        sh_outer = (grad_constraint("outer") if grad_constraint is not None
+                    else None)
         new_outer, new_outer_m = F.apply_rule_tree(
-            rule, outer, g_outer, m["outer"], labels["outer"], hp, stepf)
+            rule, outer, F._constrain(g_outer, sh_outer), m["outer"],
+            labels["outer"], hp, stepf, sh_outer)
 
         new_params = {"outer": new_outer, "shared": {},
                       "stacks": {"enc": new_enc, "dec": new_dec}}

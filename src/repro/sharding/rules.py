@@ -11,9 +11,9 @@ Logical axes:
 Rules are (regex over the param path, dim-role template) pairs; every rule
 is shape-guarded: an axis is applied to a dim only if the dim is divisible
 by the mesh axis size (e.g. whisper's vocab 51865 falls back to replicated
-instead of failing).  Optimizer state shardings are derived from the param
-spec by shape-suffix matching, so AdaLomo's factored (r, c) vectors land on
-the same devices as the rows/columns they describe.
+instead of failing).  Optimizer state shardings are derived from each
+param's own spec, so AdaLomo's factored (r, c) vectors land on the same
+devices as the rows/columns they describe.
 """
 from __future__ import annotations
 
@@ -118,34 +118,28 @@ def param_pspecs(params, axes: MeshAxes):
 
 
 def opt_pspecs(opt_state, params, param_specs, axes: MeshAxes):
-    """Derive optimizer-state specs from param specs by shape matching.
+    """Optimizer-state specs, each parameter's state derived from that
+    parameter's own spec (``opt_state.moments`` mirrors ``params``; the
+    step counter is replicated).
 
-    AdaLomo r (= param.shape[:-1]) inherits the spec minus the last dim;
-    c (= shape[:-2] + shape[-1:]) minus the second-to-last; same-shape
-    states (Adam m/v, unfactored v) inherit the full spec.
+    A state that knows how it lies with its parameter says so
+    (``pspecs(spec)``: AdaLomo's factored r with the rows it describes, c
+    with the columns); any other state leaf of the parameter's shape
+    (Adam m/v) inherits the full spec, and the rest is replicated.
     """
-    flat_p = {tuple(s.shape): spec for s, spec in zip(
-        jax.tree.leaves(params), jax.tree.leaves(
-            param_specs, is_leaf=lambda x: isinstance(x, P)))}
+    del axes
 
-    # Build a per-param lookup keyed by id of abstract shape — instead walk
-    # moments in parallel with params where possible; fall back on shapes.
-    def leaf_spec(leaf):
-        sh = tuple(leaf.shape)
-        if sh == ():
-            return P()
-        if sh in flat_p:
-            return flat_p[sh]
-        # factored r: param shape minus last dim
-        for psh, spec in flat_p.items():
-            parts = list(spec) + [None] * (len(psh) - len(spec))
-            if sh == psh[:-1]:
-                return P(*parts[:-1]) if len(parts) == len(psh) else P()
-            if len(psh) >= 2 and sh == psh[:-2] + psh[-1:]:
-                return P(*(parts[:-2] + parts[-1:]))
-        return P()
+    def for_param(param, spec, state):
+        spec = P(*(tuple(spec) + (None,) * param.ndim)[:param.ndim])
+        if hasattr(state, "pspecs"):
+            return state.pspecs(spec)
+        return jax.tree.map(
+            lambda leaf: spec if leaf.shape == param.shape else P(), state)
 
-    return jax.tree.map(leaf_spec, opt_state)
+    moments = jax.tree.map(for_param, params, param_specs,
+                           opt_state.moments)
+    return opt_state._replace(
+        step=jax.tree.map(lambda _: P(), opt_state.step), moments=moments)
 
 
 def batch_pspecs(batch, axes: MeshAxes):
@@ -265,34 +259,31 @@ def _as_tuple(a):
 
 
 def make_grad_constraint(mesh: Mesh, axes: MeshAxes, params):
-    """Per-stack gradient constraints (§Perf H2): constrain each layer
-    gradient to its parameter's sharding before the optimizer consumes it.
-    Turns the fp32 full-tensor all-reduce of dW into a bf16 reduce-scatter;
-    the factored (r,c) statistics then cost only O(m+n) cross-shard traffic.
+    """Gradient shardings (§Perf H2) for the fused step: each gradient is
+    constrained to its parameter's sharding before the optimizer consumes
+    it, which turns the fp32 full-tensor all-reduce of dW into a
+    reduce-scatter; the rule, told the same sharding, updates each
+    device's shard, and the factored (r,c) statistics cost only O(m+n)
+    cross-shard traffic.
 
-    Returns ``fn(stack_name) -> (g_layer_tree -> constrained tree)``.
+    Returns ``fn(group) -> NamedSharding tree``: for a stack name, the
+    shardings of one layer's slice (the leading layer dim stripped); for
+    ``"outer"`` or ``"shared"``, those of the leaves as they are.
     """
     specs = param_pspecs(params, axes)
 
-    def for_stack(stack_name: str):
-        sub = specs["stacks"][stack_name]
+    def slice_sharding(spec: P):
+        # strip the leading (layer) dim of the stacked spec
+        parts = list(spec)
+        return NamedSharding(mesh, P(*parts[1:]) if parts else P())
 
-        def slice_sharding(spec: P):
-            # strip the leading (layer) dim of the stacked spec
-            parts = list(spec)
-            return NamedSharding(mesh, P(*parts[1:]) if parts else P())
+    def for_group(name: str):
+        if name in specs["stacks"]:
+            return jax.tree.map(slice_sharding, specs["stacks"][name],
+                                is_leaf=lambda x: isinstance(x, P))
+        return to_shardings(specs[name], mesh)
 
-        shardings = jax.tree.map(slice_sharding, sub,
-                                 is_leaf=lambda x: isinstance(x, P))
-
-        def constrain(g_tree):
-            return jax.tree.map(
-                lambda g, sh: jax.lax.with_sharding_constraint(g, sh),
-                g_tree, shardings)
-
-        return constrain
-
-    return for_stack
+    return for_group
 
 
 def make_residual_constraint(mesh: Mesh, axes: MeshAxes):
