@@ -279,6 +279,11 @@ def _call(kernel, name, grid, tables, in_specs, args, meta, g, out_specs,
     if "qs" in meta:
         in_specs += [g.q_meta(), g.kv_meta()]
         args += [meta["qs"], meta["ks"]]
+    # inside ``shard_map`` the outputs vary over the mesh axes the inputs
+    # vary over (none outside it)
+    vma = frozenset().union(*(jax.typeof(a).vma for a in args))
+    out_shape = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, vma=vma), out_shape)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -6,13 +6,16 @@ or per row (B, S)), pads both sequences to block multiples, moves the
 tensors into the kernels' tile layouts and back, and differentiates
 through ``flash_bwd``.  It saves the residuals the ``jnp`` custom VJP in
 ``models/layers.py`` saves (q, k, v, out and the per-row log-sum-exp),
-here in the kernels' layouts.  ``models.layers.attention`` chooses it on
-TPU; ``interpret=True`` runs the kernels on the CPU for tests.
+here in the kernels' layouts.  ``flash_attention_sharded`` runs the same
+call inside ``shard_map`` on a multi-device mesh, each device on its own
+kv heads.  ``models.layers.attention`` chooses one of them on TPU;
+``interpret=True`` runs the kernels on the CPU for tests.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.flash_attention.flash_attention import (
     BLOCK_KV, BLOCK_Q, flash_bwd, flash_fwd, plan)
@@ -118,18 +121,21 @@ def flash_attention(q, k, v, q_pos, kv_pos, *, scale, causal=True,
     meta, bq, Sp, Skvp, static = _setup(q, k, q_pos, kv_pos, q_seg, kv_seg,
                                         causal, window, block_q, block_kv)
 
+    # the block tables enter as an argument and a residual, not a closure:
+    # under ``shard_map`` the backward runs in another trace than the
+    # forward that made them
     @jax.custom_vjp
-    def fa(q, k, v):
+    def fa(q, k, v, meta):
         return _forward(q, k, v, meta, bq, Sp, Skvp, scale, static,
                         interpret)[0]
 
-    def fwd(q, k, v):
+    def fwd(q, k, v, meta):
         out, lse, (qt, kt, vt) = _forward(q, k, v, meta, bq, Sp, Skvp, scale,
                                           static, interpret)
-        return out, (qt, kt, vt, out, lse)
+        return out, (qt, kt, vt, out, lse, meta)
 
     def bwd(res, dout):
-        qt, kt, vt, out, lse = res
+        qt, kt, vt, out, lse, meta = res
         dd = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
                      axis=-1)                              # D: [B,Sq,K,G]
         dqt, dk, dv = flash_bwd(
@@ -138,7 +144,29 @@ def flash_attention(q, k, v, q_pos, kv_pos, *, scale, causal=True,
             interpret=interpret, **static)
         dq = _from_tiles_t(dqt, G)[:, :Sq]
         return (dq, dk.transpose(0, 2, 1, 3)[:, :Skv],
-                dv.transpose(0, 2, 1, 3)[:, :Skv])
+                dv.transpose(0, 2, 1, 3)[:, :Skv], None)
 
     fa.defvjp(fwd, bwd)
-    return fa(q, k, v)
+    return fa(q, k, v, meta)
+
+
+def flash_attention_sharded(q, k, v, q_pos, kv_pos, *, mesh, head_axis,
+                            q_seg=None, kv_seg=None, **kw):
+    """``flash_attention`` on ``mesh``, each device on its own kv heads: q,
+    k, v and the output split over ``head_axis`` along K (a device's q
+    heads are the G groups of its kv heads), the batch, the sequences and
+    the positions and segment ids whole on every device.  A ``pallas_call``
+    has no partitioner; ``shard_map`` runs it per device and transposes its
+    custom VJP, so the backward kernels also run on each device's heads.
+    ``head_axis`` must divide K."""
+    heads = P(None, None, head_axis)
+    ids = (q_pos, kv_pos) + (() if q_seg is None else (q_seg, kv_seg))
+
+    def local(q, k, v, q_pos, kv_pos, *seg):
+        q_seg, kv_seg = seg or (None, None)
+        return flash_attention(q, k, v, q_pos, kv_pos, q_seg=q_seg,
+                               kv_seg=kv_seg, **kw)
+
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(heads,) * 3 + (P(),) * len(ids),
+                         out_specs=heads)(q, k, v, *ids)

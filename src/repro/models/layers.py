@@ -6,11 +6,14 @@ cleanly under pjit.  Attention supports GQA/MQA, sliding windows (SWA),
 qk-norm, prefix-LM masks and cross-attention, with a two-level blockwise
 (flash-style) path for long sequences that never materializes an S×S score
 matrix.  On a TPU that path runs as the Pallas flash-attention kernels
-(``kernels/flash_attention``) wherever their masks cover it.
+(``kernels/flash_attention``) wherever their masks cover it: on one device,
+and under a mesh whose tensor-parallel axis divides the kv heads, each
+device on its own heads.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -502,11 +505,24 @@ def _swa_gather_attention(q, k, v, q_pos, kv_pos, spec, scale, q_block: int):
     return out[:, :Sq]
 
 
-def _one_device() -> bool:
-    """No multi-device mesh in context: a ``pallas_call`` has no
-    partitioner, so the flash kernels run only where nothing is sharded."""
+def _flash_kernel(K: int):
+    """The flash kernels' entry for the mesh in context, or None where they
+    cannot run.  A ``pallas_call`` has no partitioner: with no multi-device
+    mesh it runs whole; on a mesh whose tensor-parallel axis (as
+    ``rules.MeshAxes`` resolves it) divides the K kv heads it runs inside
+    ``shard_map``, each device on its own heads; on any other mesh the
+    ``jnp`` scan partitions instead."""
+    from repro.kernels.flash_attention import ops
+    from repro.sharding.rules import MeshAxes
     mesh = jax.sharding.get_abstract_mesh()
-    return mesh.empty or mesh.size == 1
+    if mesh.empty or mesh.size == 1:
+        return ops.flash_attention
+    axes = MeshAxes(mesh)
+    tp = axes.size(axes.tp)
+    if tp > 1 and K % tp == 0:
+        return functools.partial(ops.flash_attention_sharded, mesh=mesh,
+                                 head_axis=axes.tp[0])
+    return None
 
 
 def attention(
@@ -556,13 +572,13 @@ def attention(
             tiles = seq_tiles(Sq)
             k = shard_act(k, "kv_full")
             v = shard_act(v, "kv_full")
-            if (use_flash_vjp and tiles == 1 and Sq == Skv
-                    and not spec.has_prefix and _one_device()
-                    and jax.default_backend() == "tpu"):
-                from repro.kernels.flash_attention.ops import flash_attention
-                out = flash_attention(qg, k, v, q_pos, kv_pos, scale=scale,
-                                      causal=spec.causal, window=spec.window,
-                                      q_seg=q_seg, kv_seg=kv_seg)
+            kernel = (_flash_kernel(K) if use_flash_vjp and tiles == 1
+                      and Sq == Skv and not spec.has_prefix
+                      and jax.default_backend() == "tpu" else None)
+            if kernel is not None:
+                out = kernel(qg, k, v, q_pos, kv_pos, scale=scale,
+                             causal=spec.causal, window=spec.window,
+                             q_seg=q_seg, kv_seg=kv_seg)
             else:
                 impl = _flash_attention if use_flash_vjp else _block_attention
                 out = impl(qg, k, v, q_pos, kv_pos, spec, prefix_len,

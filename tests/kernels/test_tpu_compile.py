@@ -10,6 +10,7 @@ module is imported: only one process at a time may load the TPU library,
 and pytest-xdist workers all import every test file.
 """
 import dataclasses
+import math
 import os
 import re
 
@@ -98,21 +99,27 @@ FLASH_KERNELS = ("flash_attention_fwd", "flash_attention_bwd_dkv",
                  "flash_attention_bwd_dq")
 
 
-def test_flash_attention_compiles_for_v5e(one_chip):
-    """Forward and backward kernels at h2o-danube-1.8b's widths: B=1,
-    S=4096, 32 query heads over 8 kv heads, dh 80, window 4096."""
-    cfg = get_arch("h2o-danube-1.8b").cfg
-    H, K, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    B, S = 1, 4096
+# B, K, G, dh, window at S=4096: h2o-danube-1.8b's widths (32 query heads
+# over 8 kv heads, window 4096), and what one chip of Qwen3-32B's 2x2 step
+# gets (both batch rows, 4 of the 8 kv heads with their 32 query heads)
+FLASH_SHAPES = {"h2o-danube-1.8b": (1, 8, 4, 80, 4096),
+                "qwen3-32b-chip-of-2x2": (2, 4, 8, 128, None)}
+
+
+@pytest.mark.parametrize("name", list(FLASH_SHAPES))
+def test_flash_attention_compiles_for_v5e(one_chip, name):
+    """Forward and backward kernels, causal, at S=4096."""
+    B, K, G, dh, window = FLASH_SHAPES[name]
+    S = 4096
     pos = jnp.arange(S, dtype=jnp.int32)
 
     def fwd_bwd(q, k, v, do):
         out, pullback = jax.vjp(lambda q, k, v: flash_attention(
             q, k, v, pos, pos, scale=dh ** -0.5, causal=True,
-            window=cfg.window), q, k, v)
+            window=window), q, k, v)
         return out, pullback(do)
 
-    qs = _sds(one_chip, (B, S, K, H // K, dh), jnp.bfloat16)
+    qs = _sds(one_chip, (B, S, K, G, dh), jnp.bfloat16)
     kv = _sds(one_chip, (B, S, K, dh), jnp.bfloat16)
     compiled = jax.jit(fwd_bwd).lower(qs, kv, kv, qs).compile()
     text = compiled.as_text()
@@ -196,8 +203,6 @@ def _kernel_shapes(compiled):
 
 def _whole_and_local(params, specs, mesh):
     """Each sharded matrix's last two dims, whole and one device's shard."""
-    import math
-
     from jax.sharding import PartitionSpec as P
     whole, local = set(), set()
     for leaf, pspec in zip(jax.tree.leaves(params), jax.tree.leaves(
@@ -262,3 +267,54 @@ def test_mesh_step_without_shardings_compiles_for_v5e(
     compiled, params, specs, mesh = _compile_mesh_step(
         topo, arch_id, cfg_changes, fused=fused)
     assert _kernel_shapes(compiled)
+
+
+def _collectives(compiled):
+    """Per collective kind (all-gather, all-to-all, ...): the number of ops
+    in the compiled step and the bytes of their results on one chip (an
+    async start's output half)."""
+    size = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1,
+            "u8": 1, "pred": 1}
+    count, nbytes = {}, {}
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s+(?:ROOT )?%\S+ = (.*?) (all-gather|all-reduce|"
+                     r"all-to-all|reduce-scatter|collective-permute)"
+                     r"(-start)?\(", line)
+        if not m:
+            continue
+        shapes = re.findall(r"(bf16|f16|f32|s32|u32|s8|u8|pred)\[([\d,]*)\]",
+                            m.group(1))
+        if m.group(3):
+            shapes = shapes[len(shapes) // 2:]
+        kind = m.group(2)
+        count[kind] = count.get(kind, 0) + 1
+        nbytes[kind] = nbytes.get(kind, 0) + sum(
+            size[d] * math.prod(int(x) for x in dims.split(",") if x)
+            for d, dims in shapes)
+    return count, nbytes
+
+
+def test_mesh_step_runs_flash_kernels_on_each_chips_heads_for_v5e(
+        topo, monkeypatch):
+    """Qwen3-32B at full width (d 5120, 64 query over 8 kv heads, d_ff
+    25600), 2 layers, vocabulary cut to 4096, S=4096, on the 2x2 (data,
+    model) mesh: attention runs the flash kernels inside ``shard_map``,
+    each chip on its own kv heads, and that layout adds no weight
+    gathering against the ``jnp`` scan the step takes off a TPU."""
+    cut = dict(n_layers=2, vocab=4096)
+    scan = _compile_mesh_step(topo, "qwen3-32b", cut, seq_len=4096)[0]
+    assert not any(name in scan.as_text() for name in FLASH_KERNELS)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    flash = _compile_mesh_step(topo, "qwen3-32b", cut, seq_len=4096)[0]
+    text = flash.as_text()
+    for name in FLASH_KERNELS:
+        assert name in text, name
+    (n_flash, b_flash), (n_scan, b_scan) = (_collectives(flash),
+                                            _collectives(scan))
+    # nor an all-reduce: the kernels' outputs vary only over ``model``, so
+    # the transposed ``shard_map`` sums no cotangent over ``data``
+    for kind in ("all-gather", "all-to-all", "all-reduce"):
+        assert n_flash.get(kind, 0) <= n_scan.get(kind, 0), (kind, n_flash,
+                                                             n_scan)
+    assert b_flash.get("all-gather", 0) <= b_scan.get("all-gather", 0), (
+        b_flash, b_scan)

@@ -103,8 +103,25 @@ def test_dispatch_takes_kernel_for_danube_training(on_tpu):
     assert _takes_kernel(*_danube_train_args())
 
 
+@pytest.mark.parametrize("shape", [(2, 2), (1, 4), (1, 8)],
+                         ids=["data2_model2", "model4", "model8"])
+def test_dispatch_takes_sharded_kernel_under_mesh(on_tpu, shape):
+    """A mesh whose ``model`` axis divides danube's 8 kv heads runs the
+    kernels inside ``shard_map``, each device on its own heads."""
+    mesh = jax.sharding.AbstractMesh(shape, ("data", "model"))
+    with jax.sharding.use_abstract_mesh(mesh):
+        q, k, v = _danube_train_args()
+        q_pos = jnp.arange(q.shape[1], dtype=jnp.int32)
+        jaxpr = str(jax.make_jaxpr(lambda q, k, v: L.attention(
+            q, k, v, spec=L.MaskSpec(causal=True, window=4096), q_pos=q_pos,
+            kv_pos=q_pos))(q, k, v))
+    assert "shard_map" in jaxpr and "pallas_call" in jaxpr
+
+
 @pytest.mark.parametrize("path", ["prefix_lm", "sq_ne_skv", "seq_tiles",
-                                  "no_flash_vjp", "short", "mesh"])
+                                  "no_flash_vjp", "short", "mesh",
+                                  "mesh_kv_heads_indivisible",
+                                  "mesh_model_axis_of_one"])
 def test_dispatch_keeps_jnp_path(on_tpu, monkeypatch, path):
     args, kw = _danube_train_args(), {}
     if path == "prefix_lm":
@@ -118,8 +135,11 @@ def test_dispatch_keeps_jnp_path(on_tpu, monkeypatch, path):
         kw = dict(use_flash_vjp=False)
     elif path == "short":
         args = _danube_train_args(S=2048)
-    if path == "mesh":
-        mesh = jax.sharding.AbstractMesh((2,), ("data",))
+    meshes = {"mesh": ((2,), ("data",)),                  # no tp axis
+              "mesh_kv_heads_indivisible": ((1, 3), ("data", "model")),
+              "mesh_model_axis_of_one": ((4, 1), ("data", "model"))}
+    if path in meshes:
+        mesh = jax.sharding.AbstractMesh(*meshes[path])
         with jax.sharding.use_abstract_mesh(mesh):
             assert not _takes_kernel(*args, **kw)
     else:
